@@ -439,9 +439,10 @@ def run_long_context(model, params, quiet: bool = False) -> dict:
             [done[u].error for u in uids if done[u].error is not None]
         compiles = eng.prefill_compile_count() - compiles0
 
-        # whole-prompt bit-identity probe through the FUSED kernel: one
-        # 64-token prompt as a single natural-extent chunk vs one-shot
-        # prefill.  The contract is stated for f32 compute + f32 pools
+        # whole-prompt probe through the FUSED kernel: one 64-token
+        # prompt as a single natural-extent chunk vs one-shot prefill,
+        # to reassociation tolerance with the same argmax (two XLA
+        # programs).  The contract is stated for f32 compute + f32 pools
         # (the bench model is quantized/bf16, where even the oracle path
         # carries a cast), so probe a small f32 build of the same config.
         import jax
@@ -472,8 +473,10 @@ def run_long_context(model, params, quiet: bool = False) -> dict:
     assert len(ttft) == len(uids)
     saved = (eng.metrics["prefix_attn_bytes_gather"]
              - eng.metrics["prefix_attn_bytes"])
-    bitexact = bool(np.array_equal(np.asarray(l_chunk),
-                                   np.asarray(l_one)))
+    l_chunk, l_one = np.asarray(l_chunk), np.asarray(l_one)
+    whole_diff = float(np.max(np.abs(l_chunk - l_one)))
+    whole_match = bool(np.allclose(l_chunk, l_one, rtol=1e-5, atol=1e-6)
+                       and np.argmax(l_chunk) == np.argmax(l_one))
 
     result = {
         "requests": len(prompts),
@@ -495,7 +498,8 @@ def run_long_context(model, params, quiet: bool = False) -> dict:
         "prefill_compiles": compiles,
         "compile_bound": LC_COMPILE_BOUND,
         "fused_mode": fused_mode,
-        "whole_prompt_bitexact": bitexact,
+        "whole_prompt_match": whole_match,
+        "whole_prompt_max_abs_diff": whole_diff,
     }
     if not quiet:
         print(f"enginebench/long_context_prefix_bytes_saved,{saved},bytes"
@@ -505,9 +509,10 @@ def run_long_context(model, params, quiet: bool = False) -> dict:
         print(f"enginebench/long_context_ttft_ms_p50,"
               f"{result['ttft_ms_p50']:.1f},ms"
               f" (p99 {result['ttft_ms_p99']:.1f})")
-        print(f"enginebench/long_context_bitexact,"
-              f"{int(bitexact)},bool (whole-prompt chunk via fused"
-              f" kernel vs one-shot prefill)")
+        print(f"enginebench/long_context_whole_prompt_match,"
+              f"{int(whole_match)},bool (whole-prompt chunk via fused"
+              f" kernel vs one-shot prefill: max abs diff"
+              f" {whole_diff:.3g}, rtol 1e-5 atol 1e-6, same argmax)")
     return result
 
 

@@ -20,6 +20,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller step/token budgets")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     which = set(args.only.split(",")) if args.only else {
         "kernels", "energy", "roofline", "throughput", "quality"}
 

@@ -70,7 +70,7 @@ print(f"  long context: prefix_attn_bytes={lc['prefix_attn_bytes']} "
       f"saved={lc['prefix_attn_bytes_saved']} "
       f"({lc['prefix_bytes_saved_frac']:.0%}) "
       f"compiles={lc['prefill_compiles']} (bound {lc['compile_bound']}) "
-      f"bitexact={lc['whole_prompt_bitexact']} "
+      f"whole_prompt_match={lc['whole_prompt_match']} "
       f"ttft_ms_p50={lc['ttft_ms_p50']:.1f}")
 ft = bench["fault_tolerance"]
 print(f"  fault tolerance: goodput={ft['goodput_fraction']:.2f} "
@@ -111,9 +111,9 @@ if sc["legacy_shape_keys"] <= sc["compile_bound"]:
 # gather (zero savings means dead-tile skipping silently broke and
 # long prompts pay O(max_prefix) HBM traffic again); (b) the fused path
 # must hold the same one-executable-per-pool-key bound as the oracle;
-# (c) a whole-prompt single chunk through the kernel must stay
-# bit-identical to one-shot prefill (f32) — the empty-prefix
-# merge-weight contract.
+# (c) a whole-prompt single chunk through the kernel must match
+# one-shot prefill (f32) to reassociation tolerance with the same
+# argmax — the empty-prefix merge-weight contract, end to end.
 if lc["prefix_attn_bytes_saved"] <= 0:
     sys.exit("FAIL: long-context workload saved zero prefix-attention "
              "bytes vs the materialized-gather baseline — dead-tile "
@@ -122,9 +122,11 @@ if lc["prefill_compiles"] > lc["compile_bound"]:
     sys.exit(f"FAIL: long-context fused prefill compiled "
              f"{lc['prefill_compiles']}x (documented bound: "
              f"{lc['compile_bound']} per pool key)")
-if not lc["whole_prompt_bitexact"]:
+if not lc["whole_prompt_match"]:
     sys.exit("FAIL: whole-prompt single chunk through the fused kernel "
-             "is no longer bit-identical to one-shot prefill")
+             "no longer matches one-shot prefill (max abs diff "
+             f"{lc['whole_prompt_max_abs_diff']:.3g}; rtol 1e-5, "
+             "atol 1e-6, same argmax)")
 # Fault-isolation tripwires: (a) a fault may fail at most its own
 # request / sampling group — a larger blast radius means isolation
 # regressed into batch-wide failure; (b) a faulted run must drain with

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -209,7 +208,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                           count_tiles=return_tile_counts),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, q, k, v, k_scale, v_scale)

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -175,7 +174,7 @@ def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           n_k_blocks=nk, causal=causal, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(offs, qlens, klens, q, k, v)

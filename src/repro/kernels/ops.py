@@ -192,21 +192,14 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     ``return_tile_counts``.  An empty prefix row is exactly
     (out=0, m=-1e30, l=0), which the merge weights at exactly zero.
     """
-    b, c, h, d = q.shape
-    kvh = k_pool.shape[2]
-    hq = h // kvh
-    qg = q.reshape(b, c, kvh, hq, d)
-    bq = _largest_block(c, block_q)
-    outs = paged_prefill_attention_pallas(
-        qg, k_pool, v_pool, page_table, pfx_lens, q_lens, ks_pool, vs_pool,
+    c = q.shape[1]
+    # several q tiles: each one's rows must be a multiple of Mosaic's
+    # 8-row sublane tiling
+    bq = c if c <= block_q else _largest_block(c, block_q, mult=8)
+    return paged_prefill_attention_pallas(
+        q, k_pool, v_pool, page_table, pfx_lens, q_lens, ks_pool, vs_pool,
         block_q=bq, return_tile_counts=return_tile_counts,
         interpret=interpret)
-    out = outs[0].reshape(b, c, h, d)
-    m = jnp.moveaxis(outs[1].reshape(b, c, h), 1, 2)[..., None]
-    l = jnp.moveaxis(outs[2].reshape(b, c, h), 1, 2)[..., None]
-    if return_tile_counts:
-        return out, m, l, outs[3]
-    return out, m, l
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret",

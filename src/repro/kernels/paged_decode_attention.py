@@ -38,7 +38,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.decode_attention import (_n_valid_blocks, finish_softmax,
                                             init_softmax_state,
                                             online_softmax_tile)
-from repro.kernels.tpu_compat import compiler_params
 
 
 def _kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
@@ -140,7 +139,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
                           kv_int8=kv_int8, count_tiles=return_tile_counts),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lens, q, k_pool, v_pool, ks_pool, vs_pool)

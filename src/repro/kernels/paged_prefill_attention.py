@@ -11,7 +11,7 @@ prefix straight out of the pool instead:
     page_table      : (B, max_blocks) int32             — block ids, -1 free
     pfx_lens        : (B,) int32                        — prefix rows (= the
                       chunk's position offset; pool row t = global pos t)
-    q               : (B, C, KVH, HQ, hd)               — the chunk queries
+    q               : (B, C, H, hd)                     — the chunk queries
 
 It is the PR-1 ``paged_decode_attention`` addressing pattern generalized
 to multi-row q (the ``flash_prefill`` rectangle): the page table and the
@@ -32,9 +32,19 @@ normalized output plus the running (max, denominator) per query — so
 ``layers.attention_chunk_merge`` can merge it with the chunk's own-segment
 attention by its exact softmax-renormalization contract.  An empty prefix
 leaves the state at (out=0, m=NEG_INF, l=0), which merges with weight
-exactly zero: the whole-prompt single chunk stays bit-identical to
-one-shot prefill.  Q8_0 pools dequantize in-kernel via the per-(position,
+exactly zero: the merge then returns the chunk's own attention bit for
+bit.  Q8_0 pools dequantize in-kernel via the per-(position,
 kv-head) ``ks``/``vs`` scale gathers, same as the paged decode kernel.
+
+Block layout.  Mosaic requires the last two dims of every block to be
+multiples of (8, 128) or equal to the array's own dims.  A block of one
+KV head, ``(1, bs, 1, hd)`` over the ``(.., KVH, hd)`` pool, breaks that
+rule whenever KVH > 1, so each grid step fetches one pool block with ALL
+its KV heads, ``(1, bs, KVH, hd)`` (scales ``(1, bs, KVH)``), and loops
+over the heads inside the body.  The queries and the returned state are
+laid out head-major, ``(B, KVH, C*HQ, ..)``, so each head's rows are one
+contiguous ``(bq*HQ, hd)`` tile; the wrapper converts to and from the
+merge layout.
 
 ``return_tile_counts=True`` adds a per-(batch, kv_head) int32 output
 counting tiles whose body ran — the interpret-mode proof that dead tiles
@@ -50,7 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -63,8 +72,9 @@ def _kernel(pt_ref, pfx_ref, qlen_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     else:
         (m_scr, l_scr, acc_scr), cnt_ref = rest, None
     bb = pl.program_id(0)
-    qi = pl.program_id(2)
-    i = pl.program_id(3)                                   # logical block #
+    qi = pl.program_id(1)
+    i = pl.program_id(2)                                   # logical block #
+    kvh = k_ref.shape[2]
     pfx = pfx_ref[bb]
     qlen = qlen_ref[bb]
 
@@ -77,7 +87,7 @@ def _kernel(pt_ref, pfx_ref, qlen_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     if count_tiles:
         @pl.when((qi == 0) & (i == 0))
         def _init_cnt():
-            cnt_ref[0, 0] = 0
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     # a tile runs only if it holds live prefix keys AND its q tile holds
     # live chunk rows — both bounds are prefetched data, never compile keys
@@ -85,46 +95,45 @@ def _kernel(pt_ref, pfx_ref, qlen_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(run)
     def _tile():
-        q = q_ref[0, :, 0].astype(jnp.float32)             # (bq, hq, d)
-        bq, hq, d = q.shape
-        q2 = q.reshape(bq * hq, d)                         # rows = (pos, head)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if kv_int8:
-            k = k * ks_ref[0, :, 0][:, None]               # dequant per pos
-            v = v * vs_ref[0, :, 0][:, None]
-
-        s = jax.lax.dot_general(
-            q2, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bq*hq, bs)
         pos = i * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
         valid = pos < pfx                                  # (1, bs)
-        s = jnp.where(valid, s, NEG_INF)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)            # (bq*hq, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)      # (bs, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if kv_int8:
+                k = k * ks_ref[0, :, h:h + 1]              # dequant per pos
+                v = v * vs_ref[0, :, h:h + 1]
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (bq*hq, bs)
+            s = jnp.where(valid, s, NEG_INF)
+
+            m_prev = m_scr[h, :, :1]
+            l_prev = l_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            p = jnp.where(valid, p, 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
         if count_tiles:
-            cnt_ref[0, 0] += 1
+            cnt_ref[...] += 1
 
     @pl.when(i == n_blocks_grid - 1)
     def _finish():
-        bq, hq, d = q_ref.shape[1], q_ref.shape[3], q_ref.shape[4]
-        l = l_scr[:, :1]
-        out = acc_scr[...] / jnp.where(l > 0, l, 1.0)
-        o_ref[0, :, 0] = out.reshape(bq, hq, d).astype(o_ref.dtype)
-        m_ref[0, :, 0] = m_scr[:, 0].reshape(bq, hq)
-        l_ref[0, :, 0] = l_scr[:, 0].reshape(bq, hq)
+        for h in range(kvh):
+            l = l_scr[h, :, :1]
+            o_ref[0, h] = (acc_scr[h] / jnp.where(l > 0, l, 1.0)).astype(
+                o_ref.dtype)
+            m_ref[0, h] = m_scr[h, :, :1]
+            l_ref[0, h] = l
 
 
 def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
@@ -134,25 +143,29 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
                                    block_q: int = 128,
                                    return_tile_counts: bool = False,
                                    interpret: bool = False):
-    """q: (B, C, KVH, HQ, D) pre-scaled by 1/sqrt(D); k/v_pool:
+    """q: (B, C, H, D) pre-scaled by 1/sqrt(D); k/v_pool:
     (NB, BS, KVH, D) (int8 when ks/vs_pool (NB, BS, KVH) given);
     page_table: (B, MB) int32 block ids (-1 = unassigned); pfx_lens: (B,)
     int32 prefix lengths (each row attends pool positions < pfx_lens[b]);
     q_lens: (B,) int32 valid chunk rows (default C; q tiles fully past it
     are skipped and their state is (0, NEG_INF, 0) garbage).
 
-    Returns the prefix segment's flash state — out (B, C, KVH, HQ, D) f32,
-    m (B, C, KVH, HQ) f32, l (B, C, KVH, HQ) f32 — plus (B, KVH) int32
-    live-tile counts when ``return_tile_counts``.
+    Returns the prefix segment's flash state in
+    ``layers.attention_chunk_merge``'s ``pfx_state`` layout — out
+    (B, C, H, D) f32, m (B, H, C, 1) f32, l (B, H, C, 1) f32 — plus
+    (B, KVH) int32 live-tile counts when ``return_tile_counts``.
     """
-    b, c, kvh, hq, d = q.shape
-    nb, bs, kvh_p, d_p = k_pool.shape
-    if (kvh_p, d_p) != (kvh, d):
-        raise ValueError(f"pool heads/dim {(kvh_p, d_p)} != q {(kvh, d)}")
+    b, c, h, d = q.shape
+    nb, bs, kvh, d_p = k_pool.shape
+    if h % kvh or d_p != d:
+        raise ValueError(f"pool heads/dim {(kvh, d_p)} do not fit q "
+                         f"{(h, d)}")
+    hq = h // kvh
     block_q = min(block_q, c)
     if c % block_q:
         raise ValueError(f"C={c} not a multiple of block_q={block_q}")
     nq = c // block_q
+    rows = block_q * hq                                    # q rows per tile
     mb = page_table.shape[1]
     page_table = page_table.astype(jnp.int32)
     pfx_lens = pfx_lens.reshape(b).astype(jnp.int32)
@@ -162,6 +175,9 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
     if not kv_int8:
         ks_pool = jnp.ones((nb, bs, kvh), jnp.float32)
         vs_pool = jnp.ones((nb, bs, kvh), jnp.float32)
+    # head-major rows: (B, KVH, C*HQ, D), row = (position, head in group)
+    qh = q.reshape(b, c, kvh, hq, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, kvh, c * hq, d)
 
     def _blk(bb, i, pt_ref, pfx_ref):
         # clamp dead logical blocks onto the last live one (revisit -> no
@@ -170,45 +186,41 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
         last = jnp.maximum(pl.cdiv(pfx_ref[bb], bs) - 1, 0)
         return jnp.maximum(pt_ref[bb, jnp.minimum(i, last)], 0)
 
-    def q_map(bb, h, qi, i, pt_ref, pfx_ref, qlen_ref):
-        return (bb, qi, h, 0, 0)
+    def row_map(bb, qi, i, pt_ref, pfx_ref, qlen_ref):
+        return (bb, 0, qi, 0)
 
-    def pool_map(bb, h, qi, i, pt_ref, pfx_ref, qlen_ref):
-        return (_blk(bb, i, pt_ref, pfx_ref), 0, h, 0)
+    def pool_map(bb, qi, i, pt_ref, pfx_ref, qlen_ref):
+        return (_blk(bb, i, pt_ref, pfx_ref), 0, 0, 0)
 
-    def scale_map(bb, h, qi, i, pt_ref, pfx_ref, qlen_ref):
-        return (_blk(bb, i, pt_ref, pfx_ref), 0, h)
+    def scale_map(bb, qi, i, pt_ref, pfx_ref, qlen_ref):
+        return (_blk(bb, i, pt_ref, pfx_ref), 0, 0)
 
-    out_shape = [jax.ShapeDtypeStruct((b, c, kvh, hq, d), jnp.float32),
-                 jax.ShapeDtypeStruct((b, c, kvh, hq), jnp.float32),
-                 jax.ShapeDtypeStruct((b, c, kvh, hq), jnp.float32)]
-    out_specs = [pl.BlockSpec((1, block_q, 1, hq, d), q_map),
-                 pl.BlockSpec((1, block_q, 1, hq),
-                              lambda bb, h, qi, i, pt, pf, ql:
-                              (bb, qi, h, 0)),
-                 pl.BlockSpec((1, block_q, 1, hq),
-                              lambda bb, h, qi, i, pt, pf, ql:
-                              (bb, qi, h, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, kvh, c * hq, d), jnp.float32),
+                 jax.ShapeDtypeStruct((b, kvh, c * hq, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((b, kvh, c * hq, 1), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, kvh, rows, d), row_map),
+                 pl.BlockSpec((1, kvh, rows, 1), row_map),
+                 pl.BlockSpec((1, kvh, rows, 1), row_map)]
     if return_tile_counts:
-        out_shape.append(jax.ShapeDtypeStruct((b, kvh), jnp.int32))
+        out_shape.append(jax.ShapeDtypeStruct((b, 1, kvh), jnp.int32))
         out_specs.append(pl.BlockSpec(
-            (1, 1), lambda bb, h, qi, i, pt, pf, ql: (bb, h)))
+            (1, 1, kvh), lambda bb, qi, i, pt, pf, ql: (bb, 0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, nq, mb),
+        grid=(b, nq, mb),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hq, d), q_map),
-            pl.BlockSpec((1, bs, 1, d), pool_map),
-            pl.BlockSpec((1, bs, 1, d), pool_map),
-            pl.BlockSpec((1, bs, 1), scale_map),
-            pl.BlockSpec((1, bs, 1), scale_map),
+            pl.BlockSpec((1, kvh, rows, d), row_map),
+            pl.BlockSpec((1, bs, kvh, d), pool_map),
+            pl.BlockSpec((1, bs, kvh, d), pool_map),
+            pl.BlockSpec((1, bs, kvh), scale_map),
+            pl.BlockSpec((1, bs, kvh), scale_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((block_q * hq, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q * hq, 128), jnp.float32),   # running sum
-            pltpu.VMEM((block_q * hq, d), jnp.float32),     # acc
+            pltpu.VMEM((kvh, rows, 128), jnp.float32),     # running max
+            pltpu.VMEM((kvh, rows, 128), jnp.float32),     # running sum
+            pltpu.VMEM((kvh, rows, d), jnp.float32),       # acc
         ],
     )
 
@@ -218,11 +230,14 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
                           count_tiles=return_tile_counts),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table, pfx_lens, q_lens, q, k_pool, v_pool, ks_pool, vs_pool)
+    )(page_table, pfx_lens, q_lens, qh, k_pool, v_pool, ks_pool, vs_pool)
+    out = outs[0].reshape(b, kvh, c, hq, d).transpose(0, 2, 1, 3, 4)
+    m, l = (x.reshape(b, kvh, c, hq).transpose(0, 1, 3, 2).reshape(
+        b, h, c, 1) for x in outs[1:3])
+    state = (out.reshape(b, c, h, d), m, l)
     if return_tile_counts:
-        return outs[0], outs[1], outs[2], outs[3]
-    return outs[0], outs[1], outs[2]
+        return (*state, outs[3].reshape(b, kvh))
+    return state

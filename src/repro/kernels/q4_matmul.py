@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
-
 
 def _unpack(w_packed: jax.Array) -> jax.Array:
     """(N, K/2) int8 -> (N, K) int8, interleaved low/high nibbles."""
@@ -77,7 +75,7 @@ def q4_matvec_pallas(xq: jax.Array, xs: jax.Array, wq_packed: jax.Array,
         ],
         out_specs=pl.BlockSpec((m, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xq, xs, wq_packed, ws)

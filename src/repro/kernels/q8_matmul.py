@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
-
 
 def _kernel(xq_ref, xs_ref, wq_ref, ws_ref, o_ref, *, group_size: int,
             n_k_blocks: int):
@@ -101,7 +99,7 @@ def q8_matmul_pallas(xq: jax.Array, xs: jax.Array, wq: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                              "arbitrary")),
         interpret=interpret,

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
-
 
 def _kernel(xq_ref, xs_ref, wq_ref, ws_ref, o_ref, *, group_size: int):
     bm, k = xq_ref.shape
@@ -75,7 +73,7 @@ def q8_matvec_pallas(xq: jax.Array, xs: jax.Array, wq: jax.Array,
         ],
         out_specs=pl.BlockSpec((m, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xq, xs, wq, ws)

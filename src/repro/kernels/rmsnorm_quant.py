@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import compiler_params
-
 
 def _kernel(x_ref, gamma_ref, q_ref, s_ref, *, eps: float, group_size: int):
     x = x_ref[...].astype(jnp.float32)            # (bm, K)
@@ -70,7 +68,7 @@ def rmsnorm_quant_pallas(x: jax.Array, gamma: jax.Array, *,
             jax.ShapeDtypeStruct((m, k), jnp.int8),
             jax.ShapeDtypeStruct((m, g), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, gamma)

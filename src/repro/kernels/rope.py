@@ -21,8 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.tpu_compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, cos_ref, sin_ref, o_ref):
@@ -51,7 +50,7 @@ def rope_pallas(x: jax.Array, cos: jax.Array, sin: jax.Array, *,
         in_specs=[xspec, aspec, aspec],
         out_specs=xspec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), x.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, cos, sin)

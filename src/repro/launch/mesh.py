@@ -12,15 +12,11 @@ touch jax device state (the dry-run sets XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes):
-    # AxisType landed after jax 0.4.37; Auto is the default there anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -52,11 +48,7 @@ def make_serve_mesh(model_size: int | None = None):
             f"mesh model_size={n} needs 1..{len(devs)} devices")
     arr = np.asarray(devs[:n]).reshape(1, n)
     axes = ("data", "model")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.Mesh(
-            arr, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.sharding.Mesh(arr, axes)
+    return Mesh(arr, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

@@ -42,6 +42,14 @@ HBM_BW = 819e9                    # B/s per chip
 ICI_BW = 50e9                     # B/s per link
 V5E_POWER_W = 170.0               # per-chip board power under load
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind`` (Google
+# Cloud documentation, "TPU v5e").  A modelled figure is printed only for
+# a device kind listed here.
+PEAKS_BY_DEVICE_KIND = {
+    "TPU v5 lite": {"flops_bf16": PEAK_FLOPS_BF16,
+                    "flops_int8": PEAK_FLOPS_INT8, "hbm_bw": HBM_BW},
+}
+
 
 def step_joules(bytes_moved: float, flops: float,
                 power_w: float = V5E_POWER_W,

@@ -20,6 +20,7 @@ per-step token streaming (`--open-loop`).
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -28,6 +29,8 @@ import numpy as np
 from repro.checkpoint import store
 from repro.configs.base import get_config, reduced
 from repro.core.policy import QuantPolicy
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.roofline import PEAKS_BY_DEVICE_KIND, V5E_POWER_W
 from repro.models.model import build_model, count_params
 from repro.serving.async_serving import (first_token_latencies,
                                          latency_summary_ms,
@@ -70,6 +73,17 @@ def _print_throughput(eng, toks: int, wall: float) -> None:
     print(f"[serve] throughput: {toks/wall:,.1f} tok/s end-to-end "
           f"wall-clock | {eng.throughput_tok_s():,.1f} tok/s decode-only "
           f"(tokens_out/t_decode; the bench-gated figure)")
+
+
+def _print_energy_model(eng, toks: int) -> None:
+    # the engine's joules come from v5e roofline peaks and an assumed
+    # board power: a model, and meaningless on any other device
+    kind = jax.devices()[0].device_kind
+    joules = eng.metrics["energy_joules"]
+    if kind in PEAKS_BY_DEVICE_KIND and joules > 0:
+        print(f"[serve] modelled energy (roofline at {kind} published "
+              f"peaks, {V5E_POWER_W:.0f} W assumed; not measured): "
+              f"{joules:.3g} J -> {toks/joules:,.0f} tok/J")
 
 
 def run(arch: str = "llama2-110m", use_reduced: bool = True,
@@ -131,10 +145,7 @@ def run(arch: str = "llama2-110m", use_reduced: bool = True,
         print(f"[serve] TTFT p50 {np.median(lat)*1e3:.0f}ms  "
               f"p95 {np.percentile(lat, 95)*1e3:.0f}ms "
               f"(from arrival, {len(lat)}/{len(done)} with first token)")
-    joules = eng.metrics["energy_joules"]
-    if joules > 0:
-        print(f"[serve] roofline energy {joules:.3g} J -> "
-              f"{toks/joules:,.0f} tok/J (model, not measured)")
+    _print_energy_model(eng, toks)
     if spec_tokens > 0:
         print(f"[serve] speculation ({draft}, k={spec_tokens}): "
               f"accept_ratio {eng.metrics['accept_ratio']:.2f} "
@@ -198,7 +209,7 @@ def _run_open_loop(make_engine, prompts, max_new: int, seed: int,
           f"{report.tpot_ms['p50']:.1f}ms p99 {report.tpot_ms['p99']:.1f}ms "
           f"(from true arrival time)")
     _print_throughput(eng, toks, wall)
-    return eng, [h.req for h in handles]
+    return eng, [h.req for h in handles if h.req is not None]
 
 
 def main():
@@ -238,12 +249,20 @@ def main():
                          "--xla_force_host_platform_device_count=N)")
     ap.set_defaults(reduced=True)
     args = ap.parse_args()
-    run(args.arch, args.reduced, args.requests, args.bits, args.kv_int8,
+    configure_compile_cache()
+    _, done = run(
+        args.arch, args.reduced, args.requests, args.bits, args.kv_int8,
         args.max_seq, args.max_new, args.slots, args.ckpt_dir,
         no_quant=args.no_quant, spec_tokens=args.spec_tokens,
         draft=args.draft, open_loop=args.open_loop, rate=args.rate,
         load_factor=args.load_factor, stream=args.stream,
         stream_interval=args.stream_interval, mesh_size=args.mesh)
+    failed = [r for r in done if r.error is not None]
+    if failed or len(done) != args.requests:
+        kinds = sorted({str(r.error_kind) for r in failed})
+        sys.exit(f"[serve] FAILED: {len(failed)} of {args.requests} "
+                 f"requests errored or were rejected ({', '.join(kinds)}); "
+                 f"{len(done)} came back")
 
 
 if __name__ == "__main__":

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.core.qlinear import qdot, qeinsum
@@ -1232,13 +1233,20 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
                 # index_map: O(offs) live tiles fetched, dead tiles
                 # skipped, int8 dequantized in-kernel.  Under a mesh the
                 # pool is gathered whole first — the Pallas kernel
-                # addresses the full KVH extent, not a shard.
+                # addresses the full KVH extent, not a shard — and, as
+                # the partitioner cannot split a Mosaic call, every
+                # device runs it on its replicated operands.
                 lck = crep(lc) if mesh is not None else lc
-                pfx_state = KO.paged_prefill_attention(
+                prefix = functools.partial(KO.paged_prefill_attention,
+                                           interpret=(mode == "interpret"))
+                if mesh is not None:
+                    prefix = jax.shard_map(
+                        prefix, mesh=mesh, in_specs=PartitionSpec(),
+                        out_specs=PartitionSpec(), check_vma=False)
+                pfx_state = prefix(
                     q * (hd ** -0.5), lck["k"], lck["v"], pt_rows, offs,
                     lens, lck["ks"] if int8 else None,
-                    lck["vs"] if int8 else None,
-                    interpret=(mode == "interpret"))
+                    lck["vs"] if int8 else None)
                 out = L.attention_chunk_merge(q * (hd ** -0.5), None,
                                               None, k, v, acfg, q_pos,
                                               None, chunk_valid,

@@ -441,10 +441,15 @@ def test_fused_prefill_batch_composition_invariance_bitwise(monkeypatch):
 @pytest.mark.parametrize("kv", [None, "int8"])
 def test_fused_whole_prompt_bitexact_vs_oneshot(monkeypatch, kv):
     """A whole prompt served as ONE natural-extent chunk through the
-    fused kernel is bit-identical to one-shot prefill (f32) — the
-    kernel's empty-prefix state merges with weight exactly zero.  int8
-    pools agree to the oracle tolerance (one-shot uses a float cache, so
-    code-for-code identity is covered by the multi-chunk tests)."""
+    fused kernel matches one-shot prefill (f32) to reassociation
+    tolerance with the same argmax.  The kernel's empty-prefix state
+    merges with weight exactly zero (bitwise, see
+    test_attention_chunk_merge_accepts_kernel_state), but the fused
+    chunk step is a different XLA program from one-shot prefill, and the
+    compiler may fuse and reorder its element-wise math differently, so
+    the end-to-end contract is a tolerance.  int8 pools agree to the
+    oracle tolerance (one-shot uses a float cache, so code-for-code
+    identity is covered by the multi-chunk tests)."""
     monkeypatch.setenv("REPRO_FUSED_PREFILL", "interpret")
     m, params = _model(kv)
     rng = np.random.default_rng(9)
@@ -455,8 +460,10 @@ def test_fused_whole_prompt_bitexact_vs_oneshot(monkeypatch, kv):
     cache = _fill(alloc, cache, 1, 21)
     l_chunk, _ = m.prefill_chunk(params, jnp.asarray(prompt), cache, 1, 0)
     if kv is None:
-        np.testing.assert_array_equal(np.asarray(l_chunk),
-                                      np.asarray(l_one))
+        np.testing.assert_allclose(np.asarray(l_chunk),
+                                   np.asarray(l_one),
+                                   rtol=1e-5, atol=1e-6)
+        assert int(jnp.argmax(l_chunk)) == int(jnp.argmax(l_one))
     else:
         np.testing.assert_allclose(np.asarray(l_chunk),
                                    np.asarray(l_one),

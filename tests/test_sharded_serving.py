@@ -103,6 +103,21 @@ class TestBitIdenticalStreams:
         assert eng.pager.audit().clean
 
     @pytest.mark.parametrize("msize", MESH_SIZES)
+    def test_fused_prefill_streams_match_unsharded(self, model_params,
+                                                   msize, monkeypatch):
+        """The TPU prefix path: the Pallas prefill kernel (interpret
+        mode) runs per device under the mesh, on the gathered pool, and
+        greedy streams stay those of the unsharded engine.  Chunks of 8
+        over 16/12-token prompts give the kernel non-empty prefixes."""
+        mesh = _mesh_or_skip(msize)
+        monkeypatch.setenv("REPRO_FUSED_PREFILL", "interpret")
+        model, params = model_params
+        prompts = _prompts(sizes=(16, 12))
+        ref, _ = _serve(model, params, None, prompts=prompts)
+        got, _ = _serve(model, params, mesh, prompts=prompts)
+        assert got == ref
+
+    @pytest.mark.parametrize("msize", MESH_SIZES)
     def test_prefix_cache_warm_hit_sharded(self, model_params, msize):
         """A warm resubmission of the same prompt must hit the prefix
         index under a mesh (registration hashes host-side tokens, never
