@@ -326,17 +326,21 @@ def paged_attention_decode(q, k_pool, v_pool, page_table, length,
     view and defers to :func:`attention_decode` — the numerics the Pallas
     kernel (kernels/paged_decode_attention.py) matches, which on TPU fuses
     this gather into its BlockSpec index_map instead of materializing it.
+    The gather runs under the named scope ``kv_gather``, the attention
+    under ``attend``.
     """
     nb, bs, kvh, d = k_pool.shape
     b, mb = page_table.shape
-    safe = jnp.maximum(page_table, 0)
-    k = k_pool[safe].reshape(b, mb * bs, kvh, d)
-    v = v_pool[safe].reshape(b, mb * bs, kvh, d)
-    ks = vs = None
-    if ks_pool is not None:
-        ks = ks_pool[safe].reshape(b, mb * bs, kvh)
-        vs = vs_pool[safe].reshape(b, mb * bs, kvh)
-    return attention_decode(q, k, v, length, cfg, ks, vs)
+    with jax.named_scope("kv_gather"):
+        safe = jnp.maximum(page_table, 0)
+        k = k_pool[safe].reshape(b, mb * bs, kvh, d)
+        v = v_pool[safe].reshape(b, mb * bs, kvh, d)
+        ks = vs = None
+        if ks_pool is not None:
+            ks = ks_pool[safe].reshape(b, mb * bs, kvh)
+            vs = vs_pool[safe].reshape(b, mb * bs, kvh)
+    with jax.named_scope("attend"):
+        return attention_decode(q, k, v, length, cfg, ks, vs)
 
 
 # ---------------------------------------------------------------------------

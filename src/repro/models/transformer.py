@@ -675,46 +675,54 @@ def _attn_decode_layer_paged(p, x, cfg: ModelConfig, lcache, pt, pos,
     dead slot can never corrupt blocks reassigned to other sequences."""
     hd = cfg.hd()
     int8 = _kv_int8(cfg)
-    h = L.apply_norm(x, p["norm1"], cfg.norm_type, cfg.eps)
-    q, k, v = _decode_qkv(p["attn"], h, cfg)
-    if rope_cs is not None:
-        cos, sin = rope_cs
-        q = L.apply_rope(q, cos[:, None], sin[:, None])
-        k = L.apply_rope(k, cos[:, None], sin[:, None])
+    with jax.named_scope("qkv"):
+        h = L.apply_norm(x, p["norm1"], cfg.norm_type, cfg.eps)
+        q, k, v = _decode_qkv(p["attn"], h, cfg)
+        if rope_cs is not None:
+            cos, sin = rope_cs
+            q = L.apply_rope(q, cos[:, None], sin[:, None])
+            k = L.apply_rope(k, cos[:, None], sin[:, None])
 
-    nb, bs = lcache["k"].shape[0], lcache["k"].shape[1]
-    mb = pt.shape[1]
-    blk_idx = jnp.clip(pos // bs, 0, mb - 1)              # (B,)
-    blk_off = pos % bs
-    blk_id = jnp.take_along_axis(pt, blk_idx[:, None], axis=1)[:, 0]
-    safe = jnp.where(blk_id < 0, nb, blk_id)              # nb = OOB -> drop
+    with jax.named_scope("kv_write"):
+        nb, bs = lcache["k"].shape[0], lcache["k"].shape[1]
+        mb = pt.shape[1]
+        blk_idx = jnp.clip(pos // bs, 0, mb - 1)              # (B,)
+        blk_off = pos % bs
+        blk_id = jnp.take_along_axis(pt, blk_idx[:, None], axis=1)[:, 0]
+        safe = jnp.where(blk_id < 0, nb, blk_id)          # nb = OOB -> drop
 
-    lcache = dict(lcache)
-    if int8:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        lcache["k"] = lcache["k"].at[safe, blk_off].set(kq, mode="drop")
-        lcache["v"] = lcache["v"].at[safe, blk_off].set(vq, mode="drop")
-        lcache["ks"] = lcache["ks"].at[safe, blk_off].set(ks, mode="drop")
-        lcache["vs"] = lcache["vs"].at[safe, blk_off].set(vs, mode="drop")
-    else:
-        lcache["k"] = lcache["k"].at[safe, blk_off].set(
-            k.astype(lcache["k"].dtype), mode="drop")
-        lcache["v"] = lcache["v"].at[safe, blk_off].set(
-            v.astype(lcache["v"].dtype), mode="drop")
+        lcache = dict(lcache)
+        if int8:
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            lcache["k"] = lcache["k"].at[safe, blk_off].set(kq, mode="drop")
+            lcache["v"] = lcache["v"].at[safe, blk_off].set(vq, mode="drop")
+            lcache["ks"] = lcache["ks"].at[safe, blk_off].set(ks,
+                                                              mode="drop")
+            lcache["vs"] = lcache["vs"].at[safe, blk_off].set(vs,
+                                                              mode="drop")
+        else:
+            lcache["k"] = lcache["k"].at[safe, blk_off].set(
+                k.astype(lcache["k"].dtype), mode="drop")
+            lcache["v"] = lcache["v"].at[safe, blk_off].set(
+                v.astype(lcache["v"].dtype), mode="drop")
 
-    if cpool is not None:
-        lcache = cpool(lcache, 2)
+        if cpool is not None:
+            lcache = cpool(lcache, 2)
     acfg = L.AttnConfig(cfg.n_heads, cfg.n_kv_heads, hd)
+    # named scopes kv_gather and attend are inside
     out = L.paged_attention_decode(
         q * (hd ** -0.5), lcache["k"], lcache["v"], pt, pos + 1, acfg,
         lcache.get("ks"), lcache.get("vs"))
-    if crep is not None:
-        # heads mix in the wo contraction: gather them whole first so the
-        # reduction keeps single-device summation order (bitwise contract)
-        out = crep(out)
-    x = x + _decode_out_proj(p["attn"], out, x.dtype)
-    x = x + _mlp_or_moe(p, x[:, None, :], cfg, decode=True)[:, 0]
+    with jax.named_scope("out_proj"):
+        if crep is not None:
+            # heads mix in the wo contraction: gather them whole first so
+            # the reduction keeps single-device summation order (bitwise
+            # contract)
+            out = crep(out)
+        x = x + _decode_out_proj(p["attn"], out, x.dtype)
+    with jax.named_scope("mlp"):
+        x = x + _mlp_or_moe(p, x[:, None, :], cfg, decode=True)[:, 0]
     return x, lcache
 
 
@@ -733,9 +741,12 @@ def _decode_step_paged(params: Params, cfg: ModelConfig, cache: Cache,
     pos = cache["lens"] if positions is None else positions
     if mesh is not None and positions is not None:
         pos = crep(pos)
-    x = L.embed_lookup(params["embed"], tokens).astype(_cdt(cfg))
-    rp = pos if cfg.rope_type != "mrope" else jnp.broadcast_to(pos, (3, b))
-    rope_cs = _rope_cos_sin(cfg, rp)
+    with jax.named_scope("embed"):
+        x = L.embed_lookup(params["embed"], tokens).astype(_cdt(cfg))
+    with jax.named_scope("qkv"):
+        rp = pos if cfg.rope_type != "mrope" else \
+            jnp.broadcast_to(pos, (3, b))
+        rope_cs = _rope_cos_sin(cfg, rp)
     pt = cache["page_table"]
     lcrep = crep if mesh is not None else None
     lcpool = cpool if mesh is not None else None
@@ -746,8 +757,9 @@ def _decode_step_paged(params: Params, cfg: ModelConfig, cache: Cache,
                                         crep=lcrep, cpool=lcpool)
 
     x, new_attn = lax.scan(body, x, (params["blocks"], cache["attn"]))
-    x = L.apply_norm(x, params["final_norm"], cfg.norm_type, cfg.eps)
-    logits = L.lm_head(_head_weight(params, cfg), x)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(x, params["final_norm"], cfg.norm_type, cfg.eps)
+        logits = L.lm_head(_head_weight(params, cfg), x)
     if mesh is not None:
         logits = crep(logits)
         new_attn = cpool(new_attn, 3)
@@ -1188,9 +1200,10 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
                         q_chunk=cfg.q_chunk)
     crep, cpool = _serve_mesh_helpers(cfg, mesh)
 
+    # named chunk_step, so that its program is ``jit_chunk_step``
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def run(params, cache, toks, chunk_blk, chunk_off, pt_rows, slots,
-            offs, lens):
+    def chunk_step(params, cache, toks, chunk_blk, chunk_off, pt_rows,
+                   slots, offs, lens):
         if mesh is not None:
             params = crep(params)
             toks, chunk_blk, chunk_off, pt_rows, slots, offs, lens = crep(
@@ -1207,8 +1220,10 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
         positions = q_pos
         if cfg.rope_type == "mrope":
             positions = jnp.broadcast_to(positions, (3, b, c))
-        rope_cs = _rope_cos_sin(cfg, positions)
-        x = embed_inputs(params, cfg, {"tokens": toks})
+        with jax.named_scope("qkv"):
+            rope_cs = _rope_cos_sin(cfg, positions)
+        with jax.named_scope("embed"):
+            x = embed_inputs(params, cfg, {"tokens": toks})
 
         # key validity: pool row t sits at global position t (page
         # tables are position-ordered) and is live strictly below the
@@ -1220,14 +1235,15 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
 
         def body(h, inp):
             lp, lc = inp
-            hn = L.apply_norm(h, lp["norm1"], cfg.norm_type, cfg.eps)
-            q = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wq"])
-            k = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wk"])
-            v = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wv"])
-            if rope_cs is not None:
-                cos, sin = rope_cs
-                q = L.apply_rope(q, cos[:, :, None], sin[:, :, None])
-                k = L.apply_rope(k, cos[:, :, None], sin[:, :, None])
+            with jax.named_scope("qkv"):
+                hn = L.apply_norm(h, lp["norm1"], cfg.norm_type, cfg.eps)
+                q = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wq"])
+                k = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wk"])
+                v = qeinsum("bsd,hkd->bshk", hn, lp["attn"]["wv"])
+                if rope_cs is not None:
+                    cos, sin = rope_cs
+                    q = L.apply_rope(q, cos[:, :, None], sin[:, :, None])
+                    k = L.apply_rope(k, cos[:, :, None], sin[:, :, None])
             if fused:
                 # prefix read through the page table inside the kernel's
                 # index_map: O(offs) live tiles fetched, dead tiles
@@ -1236,74 +1252,84 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
                 # addresses the full KVH extent, not a shard — and, as
                 # the partitioner cannot split a Mosaic call, every
                 # device runs it on its replicated operands.
-                lck = crep(lc) if mesh is not None else lc
-                prefix = functools.partial(KO.paged_prefill_attention,
-                                           interpret=(mode == "interpret"))
-                if mesh is not None:
-                    prefix = jax.shard_map(
-                        prefix, mesh=mesh, in_specs=PartitionSpec(),
-                        out_specs=PartitionSpec(), check_vma=False)
-                pfx_state = prefix(
-                    q * (hd ** -0.5), lck["k"], lck["v"], pt_rows, offs,
-                    lens, lck["ks"] if int8 else None,
-                    lck["vs"] if int8 else None)
-                out = L.attention_chunk_merge(q * (hd ** -0.5), None,
-                                              None, k, v, acfg, q_pos,
-                                              None, chunk_valid,
-                                              pfx_state=pfx_state)
+                with jax.named_scope("attend"):
+                    lck = crep(lc) if mesh is not None else lc
+                    prefix = functools.partial(
+                        KO.paged_prefill_attention,
+                        interpret=(mode == "interpret"))
+                    if mesh is not None:
+                        prefix = jax.shard_map(
+                            prefix, mesh=mesh, in_specs=PartitionSpec(),
+                            out_specs=PartitionSpec(), check_vma=False)
+                    pfx_state = prefix(
+                        q * (hd ** -0.5), lck["k"], lck["v"], pt_rows, offs,
+                        lens, lck["ks"] if int8 else None,
+                        lck["vs"] if int8 else None)
+                    out = L.attention_chunk_merge(q * (hd ** -0.5), None,
+                                                  None, k, v, acfg, q_pos,
+                                                  None, chunk_valid,
+                                                  pfx_state=pfx_state)
             else:
                 # each row gathers ITS page-table row (shared blocks may
                 # appear in several rows — reads never conflict); dead or
                 # not-yet-written positions are masked via k_valid
-                kp = lc["k"][pt_rows].reshape(b, mb * bs, kvh, hd)
-                vp = lc["v"][pt_rows].reshape(b, mb * bs, kvh, hd)
-                if int8:
-                    kp = kp.astype(jnp.float32) * lc["ks"][pt_rows].reshape(
-                        b, mb * bs, kvh)[..., None]
-                    vp = vp.astype(jnp.float32) * lc["vs"][pt_rows].reshape(
-                        b, mb * bs, kvh)[..., None]
-                out = L.attention_chunk_merge(q * (hd ** -0.5),
-                                              kp.astype(k.dtype),
-                                              vp.astype(v.dtype), k, v,
-                                              acfg, q_pos, pfx_valid,
-                                              chunk_valid)
-            if mesh is not None:
-                # heads mix here: gather them whole so the wo reduction
-                # keeps single-device summation order (bitwise contract)
-                out = crep(out)
-            out = qeinsum("bshk,dhk->bsd", out, lp["attn"]["wo"])
-            h = h + out.astype(h.dtype)
-            h = h + _mlp_or_moe(lp, h, cfg)
+                with jax.named_scope("kv_gather"):
+                    kp = lc["k"][pt_rows].reshape(b, mb * bs, kvh, hd)
+                    vp = lc["v"][pt_rows].reshape(b, mb * bs, kvh, hd)
+                    if int8:
+                        kp = kp.astype(jnp.float32) * lc["ks"][
+                            pt_rows].reshape(b, mb * bs, kvh)[..., None]
+                        vp = vp.astype(jnp.float32) * lc["vs"][
+                            pt_rows].reshape(b, mb * bs, kvh)[..., None]
+                with jax.named_scope("attend"):
+                    out = L.attention_chunk_merge(q * (hd ** -0.5),
+                                                  kp.astype(k.dtype),
+                                                  vp.astype(v.dtype), k, v,
+                                                  acfg, q_pos, pfx_valid,
+                                                  chunk_valid)
+            with jax.named_scope("out_proj"):
+                if mesh is not None:
+                    # heads mix here: gather them whole so the wo
+                    # reduction keeps single-device summation order
+                    # (bitwise contract)
+                    out = crep(out)
+                out = qeinsum("bshk,dhk->bsd", out, lp["attn"]["wo"])
+                h = h + out.astype(h.dtype)
+            with jax.named_scope("mlp"):
+                h = h + _mlp_or_moe(lp, h, cfg)
 
-            lc = dict(lc)
-            if int8:
-                kq_, ks_ = _quantize_kv(k)
-                vq_, vs_ = _quantize_kv(v)
-                lc["k"] = lc["k"].at[chunk_blk, chunk_off].set(
-                    kq_, mode="drop")
-                lc["v"] = lc["v"].at[chunk_blk, chunk_off].set(
-                    vq_, mode="drop")
-                lc["ks"] = lc["ks"].at[chunk_blk, chunk_off].set(
-                    ks_, mode="drop")
-                lc["vs"] = lc["vs"].at[chunk_blk, chunk_off].set(
-                    vs_, mode="drop")
-            else:
-                lc["k"] = lc["k"].at[chunk_blk, chunk_off].set(
-                    k.astype(lc["k"].dtype), mode="drop")
-                lc["v"] = lc["v"].at[chunk_blk, chunk_off].set(
-                    v.astype(lc["v"].dtype), mode="drop")
-            if mesh is not None:
-                lc = cpool(lc, 2)
+            with jax.named_scope("kv_write"):
+                lc = dict(lc)
+                if int8:
+                    kq_, ks_ = _quantize_kv(k)
+                    vq_, vs_ = _quantize_kv(v)
+                    lc["k"] = lc["k"].at[chunk_blk, chunk_off].set(
+                        kq_, mode="drop")
+                    lc["v"] = lc["v"].at[chunk_blk, chunk_off].set(
+                        vq_, mode="drop")
+                    lc["ks"] = lc["ks"].at[chunk_blk, chunk_off].set(
+                        ks_, mode="drop")
+                    lc["vs"] = lc["vs"].at[chunk_blk, chunk_off].set(
+                        vs_, mode="drop")
+                else:
+                    lc["k"] = lc["k"].at[chunk_blk, chunk_off].set(
+                        k.astype(lc["k"].dtype), mode="drop")
+                    lc["v"] = lc["v"].at[chunk_blk, chunk_off].set(
+                        v.astype(lc["v"].dtype), mode="drop")
+                if mesh is not None:
+                    lc = cpool(lc, 2)
             return h, lc
 
         x, new_attn = lax.scan(body, x, (params["blocks"], cache["attn"]))
-        x = L.apply_norm(x, params["final_norm"], cfg.norm_type, cfg.eps)
-        if all_logits:
-            logits = L.lm_head(_head_weight(params, cfg), x)   # (b, c, V)
-        else:
-            last = jnp.clip(lens - 1, 0, c - 1)
-            logits = L.lm_head(_head_weight(params, cfg),
-                               x[jnp.arange(b), last])
+        with jax.named_scope("lm_head"):
+            x = L.apply_norm(x, params["final_norm"], cfg.norm_type,
+                             cfg.eps)
+            if all_logits:
+                logits = L.lm_head(_head_weight(params, cfg), x)  # (b,c,V)
+            else:
+                last = jnp.clip(lens - 1, 0, c - 1)
+                logits = L.lm_head(_head_weight(params, cfg),
+                                   x[jnp.arange(b), last])
         new_cache = dict(cache)
         new_cache["attn"] = cpool(new_attn, 3) if mesh is not None \
             else new_attn
@@ -1314,4 +1340,4 @@ def _prefill_chunk_fn(cfg: ModelConfig, mode: str = "oracle",
             new_cache["lens"] = crep(new_cache["lens"])
         return logits, new_cache
 
-    return run
+    return chunk_step

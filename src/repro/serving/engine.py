@@ -113,6 +113,17 @@ pairs, cached-prefix admissions, fanouts) for inspection — tests assert
 chunk/decode interleaving and prefix skips on it, and
 benchmarks/engine_bench.py reports preemption counts, prefix-cache hit
 rates and fork-sharing block savings from it.
+
+**Host spans.**  Each phase of a step runs inside a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (free when the
+profiler is off), so a profile puts the device's idle time down to the
+host work that held it: ``engine.step`` (carrying the step index that
+``plan_log`` entries record as ``step``) holds ``engine.schedule``,
+``engine.page_table``, ``engine.chunk`` (with ``engine.chunk_sync``,
+``engine.register`` and ``engine.first_token``), ``engine.compile_probe``
+and ``engine.decode_dispatch`` (with ``engine.sample``); the deferred
+half of a pipelined step runs in ``engine.finish`` (``engine.sync``,
+``engine.register``, ``engine.step_tail``).
 """
 
 from __future__ import annotations
@@ -125,6 +136,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.launch.roofline import step_joules, tree_bytes
 from repro.models.model import Model, count_params
@@ -345,13 +357,14 @@ class Engine:
         self.fault_log: List[Dict[str, Any]] = []
         self.straggler = StragglerDetector(n_hosts=1)
         # decode is the hot loop: jit once (cache/params structures are
-        # stable).  Donating the cache avoids a copy per token.
-        if mesh is not None:
-            self._decode = jax.jit(
-                functools.partial(model.decode_step, mesh=mesh),
-                donate_argnums=(1,))
-        else:
-            self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        # stable).  Donating the cache avoids a copy per token.  The named
+        # function gives the program a stable name (``jit_decode_step``).
+        step_fn = (model.decode_step if mesh is None else
+                   functools.partial(model.decode_step, mesh=mesh))
+
+        def decode_step(params, cache, tokens):
+            return step_fn(params, cache, tokens)
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
         self.key = jax.random.PRNGKey(seed)
 
         self.paged = (cache_kind == "paged"
@@ -420,7 +433,7 @@ class Engine:
                         "prefill_chunks": 0, "preemptions": 0,
                         "chunk_batch_calls": 0, "cow_copies": 0,
                         "prefix_hits": 0, "prefix_cached_tokens": 0,
-                        "prefix_evictions": 0, "fanouts": 0,
+                        "fanouts": 0,
                         "blocks_live_peak": 0,
                         "blocks_saved_by_sharing_peak": 0,
                         "prefill_compiles": 0,
@@ -450,7 +463,7 @@ class Engine:
                         "requests_rejected": 0, "nan_rows": 0,
                         "deadline_misses": 0, "shed_requests": 0,
                         "stalls": 0, "audit_repairs": 0,
-                        "audit_violations": 0, "slow_steps": 0}
+                        "slow_steps": 0}
         self._host_pt: Optional[np.ndarray] = None
         self._done_at_prefill: List[Request] = []  # first-token stops
         self._uid = 0
@@ -579,8 +592,9 @@ class Engine:
         if pending is None:
             return []
         self._pending = None
-        done = self._decode_complete(pending.decode)
-        self._step_tail(pending.plan, pending.t_step)
+        with TraceAnnotation("engine.finish"):
+            done = self._decode_complete(pending.decode)
+            self._step_tail(pending.plan, pending.t_step)
         return done
 
     def _step_impl(self, sync: bool):
@@ -603,6 +617,12 @@ class Engine:
         if not self.scheduler.has_work():
             return (done if done else None), None
         self._step += 1
+        with TraceAnnotation("engine.step", step=self._step):
+            return self._run_step(sync, done)
+
+    def _run_step(self, sync: bool, done: List[Request]):
+        """The body of :meth:`_step_impl` for a step with work: plan it,
+        execute the plan, and return ``(done, pending)``."""
         stalled = (self.faults is not None
                    and self.faults.pre_step(self._step, self.scheduler))
         if (self.paged and self.audit_interval
@@ -614,7 +634,11 @@ class Engine:
                 return done, None
         # an injected stall skips scheduling — the engine sees the
         # idle plan a wedged scheduler would have produced
-        plan = StepPlan() if stalled else self.scheduler.schedule()
+        if stalled:
+            plan = StepPlan()
+        else:
+            with TraceAnnotation("engine.schedule"):
+                plan = self.scheduler.schedule()
         now = self._now()
         for req in plan.rejected:
             req.t_done = now
@@ -642,7 +666,7 @@ class Engine:
                 self._preempt_streak = 0
         elif not plan.preempted:
             self._preempt_streak = 0
-        self.plan_log.append(plan.summary())
+        self.plan_log.append(dict(plan.summary(), step=self._step))
         for uid, cached in plan.admitted:
             # first admission wins: a preempt-resume re-admission must
             # not overwrite the request's original cache attribution
@@ -654,26 +678,26 @@ class Engine:
             self.scheduler.prefix_stats["hits"]
         self.metrics["prefix_cached_tokens"] = \
             self.scheduler.prefix_stats["cached_tokens"]
-        if self.paged:
-            self.metrics["prefix_evictions"] = \
-                self.pager.stats["evictions"]
-        if self.paged and plan.has_work():
-            # one republish per step covers this step's allocations,
-            # COW remaps, and any releases (finish/preempt) since the
-            # last one; the host copy is kept for chunk addressing so
-            # the batched calls never read the table back off-device.
-            self._host_pt = self.pager.page_table()
-            self.cache["page_table"] = self._put(self._host_pt)
-        if self.paged and plan.cows:
-            # copy-on-write: duplicate the shared blocks' rows before
-            # this step's writes land in the fresh copies.  (Counted
-            # here, not from allocator stats — a retracted victim's
-            # pair never reaches execution.)
-            src = self._put([s for s, _ in plan.cows], np.int32)
-            dst = self._put([d for _, d in plan.cows], np.int32)
-            self.cache["attn"] = _copy_pool_blocks(
-                self.cache["attn"], src, dst)
-            self.metrics["cow_copies"] += len(plan.cows)
+        if self.paged and (plan.has_work() or plan.cows):
+            with TraceAnnotation("engine.page_table"):
+                if plan.has_work():
+                    # one republish per step covers this step's
+                    # allocations, COW remaps, and any releases
+                    # (finish/preempt) since the last one; the host copy
+                    # is kept for chunk addressing so the batched calls
+                    # never read the table back off-device.
+                    self._host_pt = self.pager.page_table()
+                    self.cache["page_table"] = self._put(self._host_pt)
+                if plan.cows:
+                    # copy-on-write: duplicate the shared blocks' rows
+                    # before this step's writes land in the fresh copies.
+                    # (Counted here, not from allocator stats — a
+                    # retracted victim's pair never reaches execution.)
+                    src = self._put([s for s, _ in plan.cows], np.int32)
+                    dst = self._put([d for _, d in plan.cows], np.int32)
+                    self.cache["attn"] = _copy_pool_blocks(
+                        self.cache["attn"], src, dst)
+                    self.metrics["cow_copies"] += len(plan.cows)
         t_step = self._now()
         if plan.prefills:
             done.extend(self._run_chunks(plan.prefills))
@@ -681,8 +705,9 @@ class Engine:
             # executable count must stay pinned at one per pool key
             # however traffic churns chunk lengths / offsets / batch
             # width (gated by tests + the shape_churn benchmark)
-            self.metrics["prefill_compiles"] = \
-                self.prefill_compile_count()
+            with TraceAnnotation("engine.compile_probe"):
+                self.metrics["prefill_compiles"] = \
+                    self.prefill_compile_count()
             self.plan_log[-1]["prefill_compiles"] = \
                 self.metrics["prefill_compiles"]
         if self._done_at_prefill:
@@ -709,13 +734,15 @@ class Engine:
             # only re-enter circulation at the next schedule(), so
             # nothing executed this step can observe the rollback
             done.extend(self._run_verifies(plan.verifies))
-            self.metrics["verify_compiles"] = \
-                self.verify_compile_count()
+            with TraceAnnotation("engine.compile_probe"):
+                self.metrics["verify_compiles"] = \
+                    self.verify_compile_count()
             self.plan_log[-1]["verify_compiles"] = \
                 self.metrics["verify_compiles"]
         self._step_tail(plan, t_step)
         return done, None
 
+    @functools.partial(annotate_function, name="engine.step_tail")
     def _step_tail(self, plan: StepPlan, t_step: float) -> None:
         """Per-step accounting that must run after the step's tokens
         have landed (spec ratios read ``tokens_out``; sharing peaks
@@ -922,7 +949,6 @@ class Engine:
         if report.clean:
             return []
         self.metrics["audit_repairs"] += 1
-        self.metrics["audit_violations"] += len(report.violations)
         victims: Dict[int, Request] = {}
         for slot in report.victim_slots:
             seq = self.scheduler.running.get(slot)
@@ -988,6 +1014,7 @@ class Engine:
         self._account_energy(float(ln.sum()), pairs,
                              float(live_tiles * bs))
 
+    @functools.partial(annotate_function, name="engine.chunk")
     def _run_chunks(self, chunks: List[PrefillChunk]) -> List[Request]:
         """Execute ALL of this step's planned chunks — paged: one
         shape-stable batched ``prefill_chunk_batch`` call, padded to the
@@ -1027,8 +1054,11 @@ class Engine:
                 row_uids = [c.seq.req.uid for c in chunks]
                 logits = self.faults.corrupt_logits(
                     SITE_PREFILL, self._step, logits, row_uids)
-            finite = (np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-                      if self.nan_guard else None)
+            finite = None
+            if self.nan_guard:
+                with TraceAnnotation("engine.chunk_sync"):
+                    finite = np.asarray(
+                        jnp.all(jnp.isfinite(logits), axis=-1))
             for i, c in enumerate(chunks):
                 seq = c.seq
                 if self.scheduler.running.get(seq.slot) is not seq:
@@ -1108,15 +1138,16 @@ class Engine:
             seq.resuming = False
             return
         n = req.n_samples
-        keys = jnp.stack([jax.random.fold_in(self._seq_key(seq), 0)]
-                         if n == 1 else
-                         [jax.random.fold_in(
-                             jax.random.fold_in(req.rng_key,
-                                                req.stream + i), 0)
-                          for i in range(n)])
-        first = np.asarray(sample_logits_per_row(
-            keys, jnp.broadcast_to(logits[:1], (n, logits.shape[-1])),
-            req.temperature, req.top_p))
+        with TraceAnnotation("engine.first_token"):
+            keys = jnp.stack([jax.random.fold_in(self._seq_key(seq), 0)]
+                             if n == 1 else
+                             [jax.random.fold_in(
+                                 jax.random.fold_in(req.rng_key,
+                                                    req.stream + i), 0)
+                              for i in range(n)])
+            first = np.asarray(sample_logits_per_row(
+                keys, jnp.broadcast_to(logits[:1], (n, logits.shape[-1])),
+                req.temperature, req.top_p))
         if n == 1:
             sibs = [seq]
             seq.output.append(int(first[0]))
@@ -1158,18 +1189,19 @@ class Engine:
         full = seq.kv_len // bs
         if full <= seq.registered:
             return
-        # token id at pool row i is concat(prompt, output)[i]: prefill
-        # rows hold (possibly resumed) prompt tokens, each decode row
-        # holds the token fed that step — output[-1] at planning time.
-        ids = np.concatenate(
-            [seq.prompt, np.asarray(seq.output or [], np.int32)])
-        for j in range(seq.registered, full):
-            parent = seq.block_hashes[j - 1] if j else None
-            block = ids[j * bs:(j + 1) * bs]
-            h = chain_hash(parent, block)
-            seq.block_hashes.append(h)
-            self.pager.register_block(seq.slot, j, h, block)
-        seq.registered = full
+        with TraceAnnotation("engine.register"):
+            # token id at pool row i is concat(prompt, output)[i]: prefill
+            # rows hold (possibly resumed) prompt tokens, each decode row
+            # holds the token fed that step — output[-1] at planning time.
+            ids = np.concatenate(
+                [seq.prompt, np.asarray(seq.output or [], np.int32)])
+            for j in range(seq.registered, full):
+                parent = seq.block_hashes[j - 1] if j else None
+                block = ids[j * bs:(j + 1) * bs]
+                h = chain_hash(parent, block)
+                seq.block_hashes.append(h)
+                self.pager.register_block(seq.slot, j, h, block)
+            seq.registered = full
 
     def _merge_slot_cache(self, slot: int, pcache: Any, plen: int) -> None:
         """Copy a (1, …) prefill cache into slot ``slot`` of the dense
@@ -1211,6 +1243,7 @@ class Engine:
         what it computes."""
         return self._decode_complete(self._decode_dispatch(slots))
 
+    @functools.partial(annotate_function, name="engine.decode_dispatch")
     def _decode_dispatch(self, slots: List[int]) -> _PendingDecode:
         """Token-independent half of the decode step: build the padded
         row inputs, dispatch the device decode and the per-row-keyed
@@ -1235,11 +1268,15 @@ class Engine:
             tokens[i] = seq.output[-1]
             temps[i] = seq.req.temperature
             top_ps[i] = seq.req.top_p
-            key_rows[i] = jax.random.fold_in(self._seq_key(seq),
-                                             len(seq.output))
             row_uids[i] = seq.req.uid
-        zero = jax.random.PRNGKey(0)
-        keys = jnp.stack([k if k is not None else zero for k in key_rows])
+        with TraceAnnotation("engine.sample"):
+            for i in slots:
+                seq = self.scheduler.running[i]
+                key_rows[i] = jax.random.fold_in(self._seq_key(seq),
+                                                 len(seq.output))
+            zero = jax.random.PRNGKey(0)
+            keys = jnp.stack([k if k is not None else zero
+                              for k in key_rows])
 
         t0 = self._now()
         if self.faults is not None:
@@ -1249,10 +1286,11 @@ class Engine:
         if self.faults is not None:
             logits = self.faults.corrupt_logits(
                 SITE_DECODE, self._step, logits, row_uids)
-        finite = (jnp.all(jnp.isfinite(logits), axis=-1)
-                  if self.nan_guard else None)
-        nxt = sample_logits_per_row(
-            keys, logits, jnp.asarray(temps), jnp.asarray(top_ps))
+        with TraceAnnotation("engine.sample"):
+            finite = (jnp.all(jnp.isfinite(logits), axis=-1)
+                      if self.nan_guard else None)
+            nxt = sample_logits_per_row(
+                keys, logits, jnp.asarray(temps), jnp.asarray(top_ps))
         self.metrics["decode_steps"] += 1
         self.metrics["seq_steps"] += len(slots)
         kv_now = sum(self.scheduler.running[i].kv_len for i in slots
@@ -1273,8 +1311,9 @@ class Engine:
                 self.scheduler.device_lens(), np.int32)
             return p.failed
         slots, failed = p.slots, p.failed
-        finite = np.asarray(p.finite) if p.finite is not None else None
-        nxt = np.asarray(p.nxt)
+        with TraceAnnotation("engine.sync"):
+            finite = np.asarray(p.finite) if p.finite is not None else None
+            nxt = np.asarray(p.nxt)
         self.metrics["t_decode"] += self._now() - p.t0
 
         finished: List[Request] = []
