@@ -20,15 +20,16 @@ greedy.  It checks that
     (random weights give the end-of-sequence id no meaning, so it is
     switched off);
   * the chunk step takes the prefix read through the compiled Pallas
-    kernel (``tpu_custom_call``), not the jnp gather;
+    kernel (``tpu_custom_call``), not the jnp gather, and the decode
+    step its attention through the Pallas paged decode kernel;
   * the served chunk step's last-position logits for the long prompt
     match one-shot ``prefill``, which uses no Pallas, on the same params;
   * each served greedy token is the reference's choice up to that
     tolerance, with the reference teacher-forced on the served stream.
 
 ``--mesh N`` runs only the N-chip tensor-parallel engine and its 1-chip
-twin on the same requests, and checks that their greedy streams are
-identical.
+twin on the same requests, checks that both compiled steps carry their
+kernels, and that their greedy streams are identical.
 
 The figures it prints come from a smoke run, not a benchmark.  The last
 line of stdout is ``{"ok": true, "device": {...}}``; any failure exits
@@ -132,6 +133,16 @@ def chunk_step_text(eng) -> str:
     step = transformer._prefill_chunk_fn(
         eng.model.cfg, transformer.prefill_fused_mode(), mesh=eng.mesh)
     return step.lower(eng.params, eng.cache, *args).compile().as_text()
+
+
+def has_decode_kernel(eng) -> bool:
+    """Whether the engine's compiled decode step runs the Pallas paged
+    decode kernel (a ``tpu_custom_call`` named after it)."""
+    tokens = np.zeros((eng.max_slots,), np.int32)
+    text = eng._decode.lower(eng.params, eng.cache, tokens).compile() \
+        .as_text()
+    return any("tpu_custom_call" in line and "paged_decode_attention" in line
+               for line in text.splitlines())
 
 
 def chunked_logits(eng, prompt) -> np.ndarray:
@@ -256,6 +267,11 @@ def run_one_chip(seed: int) -> None:
              "prefix read is not the Pallas kernel")
     print("[smoke] chunk step: compiled with the Pallas prefill kernel "
           "(tpu_custom_call)")
+    if not has_decode_kernel(eng):
+        fail("the compiled decode step holds no paged_decode_attention "
+             "tpu_custom_call: decode attention is not the Pallas kernel")
+    print("[smoke] decode step: compiled with the Pallas paged decode "
+          "kernel (tpu_custom_call)")
 
     prompts = {**phases[0], **phases[1]}
     logits_before = make_reference(model, params, eng.max_seq)
@@ -297,6 +313,9 @@ def run_mesh(seed: int, size: int) -> None:
         if "tpu_custom_call" not in chunk_step_text(eng):
             fail(f"{tag}: the compiled chunk step holds no "
                  "tpu_custom_call")
+        if not has_decode_kernel(eng):
+            fail(f"{tag}: the compiled decode step holds no "
+                 "paged_decode_attention tpu_custom_call")
         engines[tag] = eng
         streams[tag] = {n: list(r.output) for n, r in done.items()}
     one, sharded = streams["1 chip"], streams[f"mesh {size}"]

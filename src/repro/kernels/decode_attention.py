@@ -56,9 +56,6 @@ def _n_valid_blocks(length, block_s: int):
 
 
 # -- streaming-softmax tile primitives --------------------------------------
-# Shared by this kernel and kernels/paged_decode_attention.py (which only
-# differs in how tiles are *addressed*), so the two can never drift
-# numerically — paged vs dense bit-exactness is a test invariant.
 
 
 def init_softmax_state(m_scr, l_scr, acc_scr):

@@ -322,12 +322,14 @@ def paged_attention_decode(q, k_pool, v_pool, page_table, length,
 
     q: (B, H, D) pre-scaled; k/v_pool: (NB, BS, KVH, D); page_table (B, MB)
     int32 (-1 = unassigned; such blocks read pool block 0 and are masked by
-    ``length``); length (B,).  Gathers each row's blocks into a contiguous
-    view and defers to :func:`attention_decode` — the numerics the Pallas
-    kernel (kernels/paged_decode_attention.py) matches, which on TPU fuses
-    this gather into its BlockSpec index_map instead of materializing it.
-    The gather runs under the named scope ``kv_gather``, the attention
-    under ``attend``.
+    ``length``); length (B,).  Gathers each row's whole page-table span
+    into a contiguous view and defers to :func:`attention_decode`.  This
+    is the decode step's oracle path (mode "oracle" of
+    ``transformer.prefill_fused_mode``, the default off a TPU); on a TPU
+    the step runs the Pallas kernel (kernels/paged_decode_attention.py)
+    instead, which matches these numerics and reads only live pages
+    through the page table.  The gather runs under the named scope
+    ``kv_gather``, the attention under ``attend``.
     """
     nb, bs, kvh, d = k_pool.shape
     b, mb = page_table.shape

@@ -664,15 +664,63 @@ def _serve_mesh_helpers(cfg: ModelConfig, mesh):
     return crep, cpool
 
 
+def _paged_decode_attend(cfg: ModelConfig, mode: str, mesh=None):
+    """The decode step's attention over one layer's block pool:
+    ``attend(q, lcache, pt, length) -> (B, H, hd)`` in ``q``'s dtype.
+
+    Mode "oracle" (see :func:`prefill_fused_mode`) gathers each row's
+    whole page-table span and runs ``layers.attention_decode`` over it
+    (``layers.paged_attention_decode``).  Modes "kernel"/"interpret" run
+    the Pallas kernel (`kernels.paged_decode_attention`), which reads each
+    row's live pages through the page table in its index_map and keeps
+    the softmax in VMEM.  Under a mesh the kernel runs under
+    ``shard_map`` with the pool's own KV-heads spec: attention per head is
+    local, so each device reads only its heads' pages, and where
+    ``pool_model_axis`` replicates the pool the kernel runs replicated."""
+    if mode == "oracle":
+        acfg = L.AttnConfig(cfg.n_heads, cfg.n_kv_heads, cfg.hd())
+
+        def attend(q, lc, pt, length):
+            # named scopes kv_gather and attend are inside
+            return L.paged_attention_decode(q, lc["k"], lc["v"], pt,
+                                            length, acfg, lc.get("ks"),
+                                            lc.get("vs"))
+        return attend
+
+    from repro.kernels import ops as KO
+    kernel = functools.partial(KO.paged_decode_attention,
+                               interpret=(mode == "interpret"))
+    int8 = _kv_int8(cfg)
+    if mesh is not None:
+        from repro.distribution.sharding import pool_model_axis
+        ax = pool_model_axis(cfg, mesh)
+        heads = PartitionSpec(None, ax, None)            # q, out: (B, H, hd)
+        pool = PartitionSpec(None, None, ax, None)       # (NB, BS, KVH, hd)
+        scale = PartitionSpec(None, None, ax)            # (NB, BS, KVH)
+        kernel = jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(heads, pool, pool, PartitionSpec(), PartitionSpec())
+            + ((scale, scale) if int8 else ()),
+            out_specs=heads, check_vma=False)
+
+    def attend(q, lc, pt, length):
+        with jax.named_scope("attend"):
+            scales = (lc["ks"], lc["vs"]) if int8 else ()
+            out = kernel(q, lc["k"], lc["v"], pt, length, *scales)
+            return out.astype(q.dtype)
+    return attend
+
+
 def _attn_decode_layer_paged(p, x, cfg: ModelConfig, lcache, pt, pos,
-                             rope_cs, crep=None, cpool=None):
+                             rope_cs, attend, crep=None, cpool=None):
     """One decode layer against the block pool.
 
     lcache: {"k"/"v": (NB, BS, KVH, hd), ["ks"/"vs": (NB, BS, KVH)]};
-    pt: (B, MB) int32 page table; pos: (B,) current lengths.  The new
-    token's K/V scatter into each slot's current (block, offset); released
-    slots (page_table row -1) scatter out-of-bounds and are dropped, so a
-    dead slot can never corrupt blocks reassigned to other sequences."""
+    pt: (B, MB) int32 page table; pos: (B,) current lengths; ``attend``
+    from :func:`_paged_decode_attend`.  The new token's K/V scatter into
+    each slot's current (block, offset); released slots (page_table row
+    -1) scatter out-of-bounds and are dropped, so a dead slot can never
+    corrupt blocks reassigned to other sequences."""
     hd = cfg.hd()
     int8 = _kv_int8(cfg)
     with jax.named_scope("qkv"):
@@ -709,11 +757,7 @@ def _attn_decode_layer_paged(p, x, cfg: ModelConfig, lcache, pt, pos,
 
         if cpool is not None:
             lcache = cpool(lcache, 2)
-    acfg = L.AttnConfig(cfg.n_heads, cfg.n_kv_heads, hd)
-    # named scopes kv_gather and attend are inside
-    out = L.paged_attention_decode(
-        q * (hd ** -0.5), lcache["k"], lcache["v"], pt, pos + 1, acfg,
-        lcache.get("ks"), lcache.get("vs"))
+    out = attend(q * (hd ** -0.5), lcache, pt, pos + 1)
     with jax.named_scope("out_proj"):
         if crep is not None:
             # heads mix in the wo contraction: gather them whole first so
@@ -727,10 +771,11 @@ def _attn_decode_layer_paged(p, x, cfg: ModelConfig, lcache, pt, pos,
 
 
 def _decode_step_paged(params: Params, cfg: ModelConfig, cache: Cache,
-                       tokens: jax.Array, positions, mesh=None
-                       ) -> Tuple[jax.Array, Cache]:
+                       tokens: jax.Array, positions, mesh=None,
+                       mode: str = "oracle") -> Tuple[jax.Array, Cache]:
     b = tokens.shape[0]
     crep, cpool = _serve_mesh_helpers(cfg, mesh)
+    attend = _paged_decode_attend(cfg, mode, mesh)
     if mesh is not None:
         params = crep(params)
         tokens = crep(tokens)
@@ -754,7 +799,7 @@ def _decode_step_paged(params: Params, cfg: ModelConfig, cache: Cache,
     def body(h, inp):
         lp, lc = inp
         return _attn_decode_layer_paged(lp, h, cfg, lc, pt, pos, rope_cs,
-                                        crep=lcrep, cpool=lcpool)
+                                        attend, crep=lcrep, cpool=lcpool)
 
     x, new_attn = lax.scan(body, x, (params["blocks"], cache["attn"]))
     with jax.named_scope("lm_head"):
@@ -785,13 +830,15 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Cache,
     """tokens (B,) int32 -> (logits (B, V) f32, updated cache).
 
     A cache carrying a ``page_table`` (init_paged_cache) routes through the
-    paged decode path; the dense per-slot reservation is the default.
-    ``mesh`` (paged path only) applies the storage-sharded /
-    compute-replicated serving constraints — see
+    paged decode path, whose attention reads the pool as
+    :func:`prefill_fused_mode` selects (the Pallas paged decode kernel on
+    a TPU, the jnp gather elsewhere); the dense per-slot reservation is
+    the default.  ``mesh`` (paged path only) applies the storage-sharded
+    / compute-replicated serving constraints — see
     :func:`_serve_mesh_helpers`; dense decode ignores it."""
     if "page_table" in cache:
         return _decode_step_paged(params, cfg, cache, tokens, positions,
-                                  mesh=mesh)
+                                  mesh=mesh, mode=prefill_fused_mode())
     b = tokens.shape[0]
     pos = cache["lens"] if positions is None else positions  # (B,) int32
     x = L.embed_lookup(params["embed"], tokens).astype(_cdt(cfg))
@@ -1110,20 +1157,22 @@ def _chunk_call_args(tokens_chunks, cache: Cache, slots, pos_offsets,
 
 
 def prefill_fused_mode() -> str:
-    """Which prefix-attention path chunked prefill uses.
+    """Which path both paged-attention reads take: chunked prefill's
+    prefix read and the decode step's attention.
 
-    ``"kernel"`` runs the fused Pallas kernel
-    (`kernels.paged_prefill_attention`): the prefix is read through the
-    page table inside the kernel's index_map — O(prefix) live tiles, no
-    materialized gather.  ``"oracle"`` keeps the jnp gather +
-    `layers.attention_chunk_merge` reference.  ``"interpret"`` is the
-    kernel in Pallas interpret mode (CPU-executable — what the parity
-    tests and the bench's bit-identity probe run).
+    ``"kernel"`` runs the Pallas kernels
+    (`kernels.paged_prefill_attention`, `kernels.paged_decode_attention`):
+    K/V are read through the page table inside each kernel's index_map —
+    live pages only, no materialized gather.  ``"oracle"`` keeps the jnp
+    gathers (`layers.attention_chunk_merge`,
+    `layers.paged_attention_decode`).  ``"interpret"`` is the kernels in
+    Pallas interpret mode (CPU-executable — what the parity tests and the
+    bench's bit-identity probe run).
 
     Policy: the ``REPRO_FUSED_PREFILL`` env var (kernel/oracle/interpret,
-    with on/1 and off/0 aliases) wins; default is the kernel on real TPU
-    backends and the oracle elsewhere — the same dispatch rule as the
-    decode kernels, so CPU test/bench numerics are unchanged by default.
+    with on/1 and off/0 aliases) wins; default is the kernels on real TPU
+    backends and the oracle elsewhere, so CPU test/bench numerics are
+    unchanged by default.
     """
     v = os.environ.get("REPRO_FUSED_PREFILL", "").strip().lower()
     if v in ("kernel", "on", "1", "true"):

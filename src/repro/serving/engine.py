@@ -442,6 +442,11 @@ class Engine:
                         # the page table vs the legacy full-extent gather
                         "prefix_attn_bytes": 0,
                         "prefix_attn_bytes_gather": 0,
+                        # the same for the decode step's attention: live
+                        # pages through the page table vs every decode
+                        # row's full page-table span
+                        "decode_attn_bytes": 0,
+                        "decode_attn_bytes_gather": 0,
                         # speculative decoding: drafts proposed/accepted,
                         # verify device calls + their compile bound, and
                         # rejection rollbacks (block-pool truncations).
@@ -1014,6 +1019,19 @@ class Engine:
         self._account_energy(float(ln.sum()), pairs,
                              float(live_tiles * bs))
 
+    def _account_decode_bytes(self, kv_lens: np.ndarray) -> None:
+        """K/V bytes one decode step's attention reads for its decode
+        rows, whose attention lengths (this step's token included) are
+        ``kv_lens``: ``ceil(len/block_size)`` live pages each through the
+        page table (the Pallas kernel), against every row's full
+        ``max_blocks × block_size`` span (the jnp gather)."""
+        bs = self.page_size
+        page = bs * self._kv_row_bytes
+        mb = self.pager.cfg.max_blocks_per_seq
+        self.metrics["decode_attn_bytes"] += int(
+            (-(-kv_lens // bs)).sum()) * page
+        self.metrics["decode_attn_bytes_gather"] += len(kv_lens) * mb * page
+
     @functools.partial(annotate_function, name="engine.chunk")
     def _run_chunks(self, chunks: List[PrefillChunk]) -> List[Request]:
         """Execute ALL of this step's planned chunks — paged: one
@@ -1293,10 +1311,14 @@ class Engine:
                 keys, logits, jnp.asarray(temps), jnp.asarray(top_ps))
         self.metrics["decode_steps"] += 1
         self.metrics["seq_steps"] += len(slots)
-        kv_now = sum(self.scheduler.running[i].kv_len for i in slots
-                     if i in self.scheduler.running)
+        kv_lens = np.asarray([self.scheduler.running[i].kv_len
+                              for i in slots if i in self.scheduler.running],
+                             np.int64)
+        kv_now = int(kv_lens.sum())
         self._account_energy(float(len(slots)), float(kv_now),
                              float(kv_now))
+        if self.paged:
+            self._account_decode_bytes(kv_lens)
         return _PendingDecode(slots=slots, failed=failed, nxt=nxt,
                               finite=finite, t0=t0)
 

@@ -43,9 +43,11 @@ Ownership model (this is the part every caller must respect):
 The serving engine (engine.py) runs on this layout by default: it owns a
 :class:`BlockAllocator` host-side and a device pool built by
 ``models.transformer.init_paged_cache``; decode attention reads the pool
-through the page table (``kernels/paged_decode_attention.py`` on TPU, the
-gather view below as the jnp oracle) — shared blocks need no kernel
-changes, the page table indirection already handles many-to-one maps.
+through the page table — on a TPU in the Pallas kernel
+``kernels/paged_decode_attention.py``, elsewhere through the jnp gather
+of ``layers.paged_attention_decode`` (``transformer.prefill_fused_mode``
+picks) — shared blocks need no kernel changes, the page table
+indirection already handles many-to-one maps.
 """
 
 from __future__ import annotations
@@ -605,9 +607,9 @@ def gather_view(pool, page_table, lens):
     then runs exactly as on the contiguous cache; masked by ``lens``.
     Quantized pools also return the gathered (L, B, S_max, KVH) scales.
 
-    The production TPU build fuses this gather into the decode-attention
-    kernel (kernels/paged_decode_attention.py dereferences the page table
-    inside its BlockSpec index_map); the view form keeps the same numerics
+    On a TPU the served decode step does not gather: the decode-attention
+    kernel (kernels/paged_decode_attention.py) dereferences the page table
+    inside its BlockSpec index_map; the view form keeps the same numerics
     and is what the tests validate against."""
     l, nb, bs, kvh, hd = pool["k"].shape
     b, mbs = page_table.shape

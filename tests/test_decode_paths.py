@@ -92,10 +92,11 @@ def test_pruned_int8_kv_matches_reference():
 # ---------------------------------------------------------------------------
 
 
-def _paged_setup(key, nb, bs, kvh, d, b, mb, hq, lens):
+def _paged_setup(key, nb, bs, kvh, d, b, mb, hq, lens, dtype=jnp.float32):
     """Build a pool + page table with slot block lists packed arbitrarily."""
-    kp = jax.random.normal(key, (nb, bs, kvh, d))
-    vp = jax.random.normal(jax.random.fold_in(key, 1), (nb, bs, kvh, d))
+    kp = jax.random.normal(key, (nb, bs, kvh, d)).astype(dtype)
+    vp = jax.random.normal(jax.random.fold_in(key, 1),
+                           (nb, bs, kvh, d)).astype(dtype)
     q = jax.random.normal(jax.random.fold_in(key, 2), (b, kvh * hq, d)) / 8
     rng = np.random.default_rng(int(jax.random.fold_in(key, 3)[0]))
     free = list(rng.permutation(nb))
@@ -106,9 +107,27 @@ def _paged_setup(key, nb, bs, kvh, d, b, mb, hq, lens):
     return q, kp, vp, jnp.asarray(pt), jnp.asarray(lens, jnp.int32)
 
 
-@pytest.mark.parametrize("lens", [[170, 20, 0], [0, 0, 0], [256, 1, 64]])
-def test_paged_matches_dense_reference_f32(lens):
-    nb, bs, kvh, d, b, mb, hq = 16, 64, 2, 64, 3, 4, 4
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _pallas_calls(sub)
+
+
+# (kvh, hq): GQA groups, SmolLM2's 32 heads of one query each (the kernel's
+# all-heads form), Yi-1.5-6B's 4 KV heads of 8 queries
+HEADS = [(2, 4), (32, 1), (4, 8)]
+# lens: ragged with a released slot (0: page-table row of -1), all dead,
+# and 1 / a page boundary / a page boundary + 1
+LENS = [[170, 20, 0], [0, 0, 0], [256, 1, 64], [1, 64, 65]]
+
+
+@pytest.mark.parametrize("kvh,hq", HEADS)
+@pytest.mark.parametrize("lens", LENS)
+def test_paged_matches_dense_reference_f32(lens, kvh, hq):
+    nb, bs, d, b, mb = 16, 64, 64, 3, 4
     q, kp, vp, pt, lens = _paged_setup(jax.random.PRNGKey(3), nb, bs, kvh, d,
                                        b, mb, hq, lens)
     out = ops.paged_decode_attention(q, kp, vp, pt, lens, **I)
@@ -117,8 +136,27 @@ def test_paged_matches_dense_reference_f32(lens):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
-def test_paged_matches_dense_reference_int8():
-    nb, bs, kvh, d, b, mb, hq = 12, 32, 2, 64, 3, 4, 2
+@pytest.mark.parametrize("kvh,hq", HEADS)
+def test_paged_bf16_pool_takes_no_scale_operands(kvh, hq):
+    """A bf16 pool goes to the kernel as it is: no scale pools are built
+    or passed (the pallas_call reads the page table, the lengths, q, K and
+    V only), and the f32 math matches the reference on the same codes."""
+    nb, bs, d, b, mb = 16, 64, 64, 3, 4
+    q, kp, vp, pt, lens = _paged_setup(jax.random.PRNGKey(7), nb, bs, kvh, d,
+                                       b, mb, hq, [130, 64, 0], jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: ops.paged_decode_attention(*a, **I))(
+        q, kp, vp, pt, lens)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert len(calls) == 1 and len(calls[0].invars) == 5
+    out = ops.paged_decode_attention(q, kp, vp, pt, lens, **I)
+    want = ref.ref_paged_decode_attention(
+        q.reshape(b, kvh, hq, d), kp, vp, pt, lens).reshape(b, kvh * hq, d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("kvh,hq", HEADS)
+def test_paged_matches_dense_reference_int8(kvh, hq):
+    nb, bs, d, b, mb = 12, 32, 64, 3, 4
     q, kp, vp, pt, lens = _paged_setup(jax.random.PRNGKey(4), nb, bs, kvh, d,
                                        b, mb, hq, [100, 128, 0])
     kq, vq, ks, vs = _quant_kv(kp, vp)
@@ -134,9 +172,10 @@ def test_paged_matches_dense_reference_int8():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-2)
 
 
-def test_paged_tile_counts_skip_unowned_blocks():
-    nb, bs, kvh, d, b, mb, hq = 16, 64, 2, 64, 3, 8, 2
-    lens = [130, 64, 0]
+@pytest.mark.parametrize("kvh,hq", HEADS)
+@pytest.mark.parametrize("lens", [[130, 64, 0], [1, 64, 65]])
+def test_paged_tile_counts_skip_unowned_blocks(lens, kvh, hq):
+    nb, bs, d, b, mb = 16, 64, 64, 3, 8
     q, kp, vp, pt, lens_j = _paged_setup(jax.random.PRNGKey(5), nb, bs, kvh,
                                          d, b, mb, hq, lens)
     _, counts = ops.paged_decode_attention(q, kp, vp, pt, lens_j,
@@ -158,6 +197,54 @@ def test_paged_ignores_other_slots_blocks():
     vp2 = vp.at[victim].set(-99.0)
     out1 = ops.paged_decode_attention(q, kp2, vp2, pt, lens, **I)
     np.testing.assert_array_equal(np.asarray(out0[0]), np.asarray(out1[0]))
+
+
+@pytest.mark.parametrize("kv,kvh", [(None, 4), ("int8", 4), (None, 2)],
+                         ids=["mha", "mha-int8", "gqa"])
+def test_decode_step_kernel_matches_oracle(kv, kvh):
+    """The paged decode step with its attention in the Pallas kernel
+    (interpret mode) against the jnp gather, over several steps of rows
+    at mixed lengths (one across a page boundary, a released slot):
+    logits within f32 tolerance and the same greedy tokens."""
+    from repro.configs import get_config, reduced
+    from repro.models import build_model
+    from repro.models import transformer as T
+    cfg = reduced(get_config("llama2-110m")).with_(
+        compute_dtype="float32", n_kv_heads=kvh)
+    if kv:
+        cfg = cfg.with_(kv_cache_dtype=kv)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    cache = m.init_paged_cache(4, block_size=8, n_blocks=16,
+                               max_blocks_per_seq=4)
+    key = jax.random.PRNGKey(1)
+    attn = {}
+    for i, (name, buf) in enumerate(sorted(cache["attn"].items())):
+        x = jax.random.normal(jax.random.fold_in(key, i), buf.shape)
+        attn[name] = ((x * 40).astype(jnp.int8) if buf.dtype == jnp.int8
+                      else jnp.abs(x) * 0.02 if name in ("ks", "vs")
+                      else x.astype(buf.dtype))
+    pt = np.full((4, 4), -1, np.int32)
+    pt[0, :3] = [5, 2, 9]            # 15 rows -> crosses into page 2
+    pt[1, :1] = [0]                  # 6 rows
+    pt[3, :2] = [11, 3]              # 7 rows, a second page to grow into
+    cache = dict(cache, attn=attn, page_table=jnp.asarray(pt),
+                 lens=jnp.asarray([15, 6, 0, 7], jnp.int32))
+    steps = {mode: jax.jit(lambda p, c, t, mode=mode: T._decode_step_paged(
+        p, cfg, c, t, None, mode=mode)) for mode in ("oracle", "interpret")}
+    caches = {mode: cache for mode in steps}
+    toks = {mode: jnp.asarray([3, 4, 5, 6], jnp.int32) for mode in steps}
+    for _ in range(3):
+        logits = {}
+        for mode, step in steps.items():
+            logits[mode], caches[mode] = step(params, caches[mode],
+                                              toks[mode])
+            toks[mode] = jnp.argmax(logits[mode], -1).astype(jnp.int32)
+        np.testing.assert_allclose(np.asarray(logits["interpret"]),
+                                   np.asarray(logits["oracle"]),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(toks["interpret"]),
+                                      np.asarray(toks["oracle"]))
 
 
 # ---------------------------------------------------------------------------
@@ -351,3 +438,18 @@ def test_engine_int8_kv_paged():
     paged, _ = _greedy_outputs(m, params, "paged", prompts)
     dense, _ = _greedy_outputs(m, params, "dense", prompts)
     assert paged == dense
+
+
+def test_engine_counts_decode_attention_bytes():
+    """``decode_attn_bytes`` counts the live pages each decode row's
+    attention reads through the page table, ``decode_attn_bytes_gather``
+    every decode row's full page-table span.  A 5-token prompt with pages
+    of 8 decodes at attention lengths 6, 7, 8 and 9: 1 + 1 + 1 + 2 live
+    pages, against 4 x 8 pages of span."""
+    m, params = _serve_model()
+    _, eng = _greedy_outputs(m, params, "paged",
+                             [np.arange(4, 9, dtype=np.int32)])
+    cfg, item = m.cfg, eng.cache["attn"]["k"].dtype.itemsize
+    page = 8 * 2 * cfg.n_kv_heads * cfg.hd() * item * cfg.n_layers
+    assert eng.metrics["decode_attn_bytes"] == 5 * page
+    assert eng.metrics["decode_attn_bytes_gather"] == 4 * 8 * page

@@ -23,6 +23,7 @@ replication mid-admission instead of raising.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -105,10 +106,12 @@ class TestBitIdenticalStreams:
     @pytest.mark.parametrize("msize", MESH_SIZES)
     def test_fused_prefill_streams_match_unsharded(self, model_params,
                                                    msize, monkeypatch):
-        """The TPU prefix path: the Pallas prefill kernel (interpret
-        mode) runs per device under the mesh, on the gathered pool, and
-        greedy streams stay those of the unsharded engine.  Chunks of 8
-        over 16/12-token prompts give the kernel non-empty prefixes."""
+        """The TPU attention path: the Pallas prefill kernel (interpret
+        mode) runs per device under the mesh, on the gathered pool, the
+        paged decode kernel on each device's own KV heads, and greedy
+        streams stay those of the unsharded engine.  Chunks of 8 over
+        16/12-token prompts give the prefill kernel non-empty
+        prefixes."""
         mesh = _mesh_or_skip(msize)
         monkeypatch.setenv("REPRO_FUSED_PREFILL", "interpret")
         model, params = model_params
@@ -116,6 +119,45 @@ class TestBitIdenticalStreams:
         ref, _ = _serve(model, params, None, prompts=prompts)
         got, _ = _serve(model, params, mesh, prompts=prompts)
         assert got == ref
+
+    @pytest.mark.parametrize("msize", MESH_SIZES)
+    def test_decode_kernel_sharded_bit_identical(self, model_params,
+                                                 msize):
+        """The decode step's attention in the Pallas kernel (interpret
+        mode) over one layer's pool: under the mesh it runs under
+        ``shard_map`` with the pool's KV-heads spec, each device on its
+        own heads' pages, and its output is bit for bit the unsharded
+        kernel's."""
+        from repro.models import transformer as T
+        mesh = _mesh_or_skip(msize)
+        model, _ = model_params
+        cfg = model.cfg
+        pool = jax.tree_util.tree_map(lambda a: a[0], model.init_paged_cache(
+            4, block_size=4, n_blocks=16, max_blocks_per_seq=4)["attn"])
+        key = jax.random.PRNGKey(3)
+        lc = {}
+        for i, (name, buf) in enumerate(sorted(pool.items())):
+            x = jax.random.normal(jax.random.fold_in(key, i), buf.shape)
+            lc[name] = ((x * 40).astype(buf.dtype) if buf.dtype == np.int8
+                        else jnp.abs(x) * 0.02 if name in ("ks", "vs")
+                        else x.astype(buf.dtype))
+        pt = np.full((4, 4), -1, np.int32)
+        pt[0, :3] = [5, 2, 9]
+        pt[1, :2] = [0, 7]
+        pt[3, :1] = [11]
+        pt, lens = jnp.asarray(pt), jnp.asarray([11, 5, 0, 2], np.int32)
+        q = jax.random.normal(jax.random.fold_in(key, 9),
+                              (4, cfg.n_heads, cfg.hd()))
+
+        def attend(mesh):
+            return jax.jit(T._paged_decode_attend(cfg, "interpret", mesh))
+
+        want = attend(None)(q, lc, pt, lens)
+        heads = jax.sharding.NamedSharding(
+            mesh, P(None, None, sh.pool_model_axis(cfg, mesh)))
+        placed = jax.device_put(lc, heads)      # (NB, BS, KVH[, hd])
+        got = attend(mesh)(q, placed, pt, lens)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @pytest.mark.parametrize("msize", MESH_SIZES)
     def test_prefix_cache_warm_hit_sharded(self, model_params, msize):
