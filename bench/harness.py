@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from bench import model as bm
+from bench import spec
 from bench import traffic as bt
 from bench.spec import Cell
 
@@ -241,8 +242,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     rec = _window_record(run, t0, t_end, plans, counters, srv)
     rec["steps"] = window_steps(engine.plan_log, plan0, window["plan_end"])
     rec["kv_bytes"] = engine.cache["attn"]["k"].dtype.itemsize
-    rec.update(cell=cell.name, seed=seed, seconds=seconds, cfg=cfg,
-               conf=conf, setup_s=setup_s, setup_phases=phases,
+    rec.update(cell=cell.name, seed=seed, seconds=seconds,
+               work=spec.arch(conf).token_work(conf), conf=conf,
+               setup_s=setup_s, setup_phases=phases,
                setup_counters={k: m0[k] for k in ("chunk_batch_calls",
                                                   "decode_steps")},
                roles=roles,

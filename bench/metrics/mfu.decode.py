@@ -6,7 +6,7 @@ from bench import flops
 
 def read(rec):
     secs = rec["trace"]["programs"]["decode_step"]["seconds"]
-    work = sum(flops.decode_flops(rec["cfg"], s["decode"])
+    work = sum(flops.decode_flops(rec["work"], s["decode"])
                for s in rec["steps"] if s["decode"])
     if not secs or not work:
         return None

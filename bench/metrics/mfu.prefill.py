@@ -7,7 +7,8 @@ from bench import flops
 
 def read(rec):
     secs = rec["trace"]["programs"]["chunk_step"]["seconds"]
-    work = sum(flops.chunk_flops(rec["cfg"], s["chunk"]) for s in rec["steps"])
+    work = sum(flops.chunk_flops(rec["work"], s["chunk"])
+               for s in rec["steps"])
     if not secs or not work:
         return None
     peak = flops.peaks(rec["device"]["kind"])["flops_bf16"]

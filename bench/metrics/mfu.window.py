@@ -5,11 +5,11 @@ from bench import flops
 
 
 def read(rec):
-    cfg = rec["cfg"]
-    work = sum(flops.chunk_flops(cfg, s["chunk"])
-               + (flops.decode_flops(cfg, s["decode"]) if s["decode"] else 0)
+    work = rec["work"]
+    done = sum(flops.chunk_flops(work, s["chunk"])
+               + (flops.decode_flops(work, s["decode"]) if s["decode"] else 0)
                for s in rec["steps"])
-    if not work:
+    if not done:
         return None
     peak = flops.peaks(rec["device"]["kind"])["flops_bf16"]
-    return 100.0 * work / (rec["trace"]["window_s"] * peak)
+    return 100.0 * done / (rec["trace"]["window_s"] * peak)

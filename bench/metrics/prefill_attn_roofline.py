@@ -11,11 +11,10 @@ def read(rec):
     secs = rec["trace"]["kernel_s"]
     if not secs:
         return None
-    cfg = rec["cfg"]
     peak = flops.peaks(rec["device"]["kind"])
     need = 0.0
     for s in rec["steps"]:
-        f, b = flops.prefix_attn_cost(cfg, s["chunk"], rec["kv_bytes"])
+        f, b = flops.prefix_attn_cost(rec["work"], s["chunk"], rec["kv_bytes"])
         need += max(f / peak["flops_bf16"], b / peak["hbm_bytes_per_s"])
     if not need:
         return None

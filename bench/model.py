@@ -3,9 +3,10 @@
 The configuration files under ``bench/configs/`` hold the published
 ``config.json`` keys of each model, plus the serving geometry and the
 stated precision.  :func:`model_config` maps them onto the program's
-``ModelConfig``; :func:`build_weights` makes the served Q8_0 tree on the
-device from the seed in one jitted call, with the numbers
-``bench/weights.py`` draws (the same the reference draws).
+``ModelConfig`` through the architecture module that ``model_type``
+names (``bench/archs/<model_type>.py``); :func:`build_weights` makes the
+served Q8_0 tree on the device from the seed in one jitted call, with
+the numbers ``bench/weights.py`` draws (the same the reference draws).
 """
 
 from __future__ import annotations
@@ -16,7 +17,7 @@ from typing import Any, Dict
 
 import jax
 
-from bench import weights
+from bench import spec, weights
 
 
 def load_config(path) -> Dict[str, Any]:
@@ -24,19 +25,13 @@ def load_config(path) -> Dict[str, Any]:
 
 
 def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
+    """The program's ``ModelConfig`` for a configuration file: the
+    architecture's, in the stated precision."""
     prec = conf["precision"]
     if prec["weights"] != "q8_0":
         raise ValueError(f"unsupported weight format {prec['weights']!r}")
-    m = weights.dims(conf)
-    return ModelConfig(
-        arch_id=Path(conf.get("name", "bench")).stem, family="dense",
-        n_layers=m["layers"], d_model=m["d"], n_heads=m["heads"],
-        n_kv_heads=m["kv_heads"], d_ff=m["ff"], vocab_size=m["vocab"],
-        head_dim=m["hd"], rope_theta=m["theta"], eps=m["eps"],
-        tie_embeddings=m["tied"], compute_dtype=prec["compute"],
-        kv_cache_dtype=prec["kv_cache"])
+    return spec.arch(conf).program_config(conf).with_(
+        compute_dtype=prec["compute"], kv_cache_dtype=prec["kv_cache"])
 
 
 def _path_str(path) -> str:
@@ -74,7 +69,8 @@ def check_layout(model, conf: Dict[str, Any]) -> None:
            for ps, kind, shape, _, _ in weights.plan(conf)}
     if want != got:
         raise ValueError(f"the program's weight layout differs from "
-                         f"bench/weights.py: program {sorted(want.items())}"
+                         f"{spec.arch_path(conf['model_type'])}: program "
+                         f"{sorted(want.items())}"
                          f", benchmark {sorted(got.items())}")
 
 

@@ -1,13 +1,14 @@
 """Plain float32 reference of the served models, and the check it decides.
 
-A Llama-architecture decoder (RMSNorm, rotate-half RoPE, grouped-query
-attention, SwiGLU, no biases) written with ``jax.numpy`` alone, following
-the published description; it imports nothing of the program and reads
-its sizes from the configuration file.  It draws its weights layer by
-layer from the run's seed with ``bench/weights.py`` (the int8 codes and
-scales the served tree was made from, dequantized to float32), runs all
-matrix products at ``highest`` precision, and returns the logits before
-each served token.
+Each architecture's layer, final norm and head are in its module,
+``bench/archs/<model_type>.py``, written with ``jax.numpy`` alone and the
+helpers here (:func:`mm`, :func:`rms`, :func:`rope`, :func:`attention`),
+following the published description; none imports anything of the
+program, and each reads its sizes from the configuration file.  The
+reference draws its weights layer by layer from the run's seed with
+``bench/weights.py`` (the int8 codes and scales the served tree was made
+from, dequantized to float32), runs all matrix products at ``highest``
+precision, and returns the logits before each served token.
 
 ``control=True`` is the comparison's control: the same pass with every
 matrix product's operands rounded to float8 (e4m3, one scale per tensor
@@ -23,13 +24,14 @@ serving in exact arithmetic reads 0.
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import spec, weights
 
 _Q_BLOCK = 256
 _F8 = jnp.float8_e4m3fn
@@ -44,17 +46,17 @@ def _round_f8(x, axis):
     return (x / s).astype(_F8).astype(jnp.float32) * s
 
 
-def _mm(eq: str, x, w, control: bool):
+def mm(eq: str, x, w, control: bool):
     if control:
         x, w = _round_f8(x, -1), _round_f8(w, None)
     return jnp.einsum(eq, x, w, precision=jax.lax.Precision.HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (B, T, heads, hd); rotate-half layout (as HF Llama)."""
     hd = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -64,7 +66,7 @@ def _rope(x, pos, theta):
     return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
 
 
-def _attention(q, k, v, control):
+def attention(q, k, v, control):
     """Causal GQA attention, q (B, T, H, hd), k/v (B, T, KVH, hd), in
     query blocks so that the score block stays small."""
     b, t, h, hd = q.shape
@@ -76,59 +78,56 @@ def _attention(q, k, v, control):
 
     def block(start):
         qb = jax.lax.dynamic_slice_in_dim(q, start, _Q_BLOCK, axis=1)
-        s = _mm("bqhd,bkhd->bhqk", qb, k, control)
+        s = mm("bqhd,bkhd->bhqk", qb, k, control)
         qpos = start + jnp.arange(_Q_BLOCK)
         s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _mm("bhqk,bkhd->bqhd", p, v, control)
+        return mm("bhqk,bkhd->bqhd", p, v, control)
 
     outs = jax.lax.map(block, jnp.arange(0, t, _Q_BLOCK))
     return jnp.moveaxis(outs, 0, 1).reshape(b, t, h, hd)
 
 
-def _layer(x, w: Dict[str, jax.Array], pos, m, control):
-    h = _rms(x, w["blocks/norm1/gamma"], m["eps"])
-    q = _mm("btd,hkd->bthk", h, w["blocks/attn/wq"], control)
-    k = _mm("btd,hkd->bthk", h, w["blocks/attn/wk"], control)
-    v = _mm("btd,hkd->bthk", h, w["blocks/attn/wv"], control)
-    q, k = _rope(q, pos, m["theta"]), _rope(k, pos, m["theta"])
-    a = _attention(q, k, v, control)
-    x = x + _mm("bthk,dhk->btd", a, w["blocks/attn/wo"], control)
-    h = _rms(x, w["blocks/norm2/gamma"], m["eps"])
-    g = _mm("btd,fd->btf", h, w["blocks/mlp/w1"], control)
-    u = _mm("btd,fd->btf", h, w["blocks/mlp/w3"], control)
-    return x + _mm("btf,df->btd", jax.nn.silu(g) * u, w["blocks/mlp/w2"],
-                   control)
-
-
 def logits_before(conf: Dict, seed: int,
                   seqs: Sequence[Tuple[np.ndarray, List[int]]],
-                  control: bool = False) -> List[np.ndarray]:
+                  control: bool = False,
+                  root: Path = spec.ROOT) -> List[np.ndarray]:
     """For each ``(prompt, served)``: the reference logits before each
     served token, ``(len(served), vocab)`` float32, teacher-forced on
-    ``prompt + served[:-1]``.  ``conf`` is the configuration file."""
-    m = weights.dims(conf)
-    plan = weights.plan(conf)
+    ``prompt + served[:-1]``.  ``conf`` is the configuration file, whose
+    architecture module under ``root`` gives the layers."""
+    arch = spec.arch(conf, root)
+    m = arch.dims(conf)
+    plan = weights.plan(conf, root)
     key = weights.seed_key(seed)
-    lens = [len(p) + len(s) - 1 for p, s in seqs]
-    t = -(-max(lens) // _Q_BLOCK) * _Q_BLOCK
-    toks = np.zeros((len(seqs), t), np.int32)
-    for i, (p, s) in enumerate(seqs):
-        full = np.concatenate([np.asarray(p), np.asarray(s[:-1], np.int32)])
-        toks[i, :len(full)] = full
-    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), toks.shape)
     draw = jax.jit(functools.partial(weights.layer_weights, plan))
     top = draw(key)
-    x = jnp.take(top["embed"], jnp.asarray(toks), axis=0)
-    step = jax.jit(functools.partial(_layer, m=m, control=control))
+    # one request a pass, its length padded to a power of two of at
+    # least a query block, and the head over blocks of rows: a few shapes
+    # serve every run, so the persistent cache holds their programs
+    xs = []
+    for p, s in seqs:
+        full = np.concatenate([np.asarray(p), np.asarray(s[:-1], np.int32)])
+        t = max(_Q_BLOCK, 1 << (len(full) - 1).bit_length())
+        toks = np.zeros((1, t), np.int32)
+        toks[0, :len(full)] = full
+        xs.append(arch.embed(top, jnp.asarray(toks), m))
+    step = jax.jit(functools.partial(arch.layer, m=m, control=control))
     for layer in range(m["layers"]):
-        x = step(x, draw(key, jnp.uint32(layer)), pos)
-    head = top["embed"] if m["tied"] else top["lm_head"]
-    x = _rms(x, top["final_norm/gamma"], m["eps"])
+        w = draw(key, jnp.uint32(layer))
+        xs = [step(x, w, jnp.arange(x.shape[1], dtype=jnp.int32)[None])
+              for x in xs]
+    head = jax.jit(functools.partial(arch.lm_head, m=m, control=control))
     out = []
-    for i, (p, s) in enumerate(seqs):
-        rows = x[i, len(p) - 1:len(p) - 1 + len(s)]
-        out.append(np.asarray(_mm("td,vd->tv", rows, head, control)))
+    for x, (p, s) in zip(xs, seqs):
+        rows = np.asarray(arch.final_norm(x, top, m)[0])[
+            len(p) - 1:len(p) - 1 + len(s)]
+        blocks = []
+        for i in range(0, len(rows), _Q_BLOCK):
+            block = np.zeros((_Q_BLOCK, rows.shape[1]), np.float32)
+            block[:len(rows[i:i + _Q_BLOCK])] = rows[i:i + _Q_BLOCK]
+            blocks.append(np.asarray(head(jnp.asarray(block), top)))
+        out.append(np.concatenate(blocks)[:len(rows)])
     return out
 
 

@@ -3,8 +3,10 @@
 A cell (an entry of ``workloads``) names a configuration and a traffic
 mix.  Its configuration file is the ``file`` of the configuration entry,
 its mix is ``bench/traffic/<traffic>.json`` and each per-layer metric is
-read by ``bench/metrics/<metric name>.py``.  Adding a cell, a mix or a
-metric adds files and entries; no file here changes.
+read by ``bench/metrics/<metric name>.py``.  A configuration's published
+``model_type`` names its architecture, ``bench/archs/<model_type>.py``.
+Adding a cell, a mix, a metric or an architecture adds files and entries;
+no file here changes.
 """
 
 from __future__ import annotations
@@ -64,11 +66,29 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)])
 
 
-def metric_reader(name: str, root: Path = ROOT):
-    """The ``read(record)`` function of ``bench/metrics/<name>.py``."""
-    path = metric_path(name, root)
+def _load(path: Path, prefix: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+        prefix + path.stem.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """The ``read(record)`` function of ``bench/metrics/<name>.py``."""
+    return _load(metric_path(name, root), "bench_metric_").read
+
+
+def arch_path(model_type: str, root: Path = ROOT) -> Path:
+    return root / "bench" / "archs" / f"{model_type}.py"
+
+
+def arch(conf: Dict[str, Any], root: Path = ROOT):
+    """The architecture module of a configuration file,
+    ``bench/archs/<model_type>.py``: its program mapping, weight layout,
+    work per token and plain reference."""
+    path = arch_path(conf["model_type"], root)
+    if not path.exists():
+        raise FileNotFoundError(f"no architecture module {path} for "
+                                f"model_type {conf['model_type']!r}")
+    return _load(path, "bench_arch_")

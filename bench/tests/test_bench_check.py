@@ -70,58 +70,22 @@ def test_float8_control_fails_the_limit(clean):
     assert reference.control_gap(ref, ctl) > rec["check"]["limit"]
 
 
-def _broken_run(monkeypatch, make_decode=None, alter_token=False):
-    from repro.serving import engine as eng_mod
-    real_init = eng_mod.Engine.__init__
-
-    def init(self, *a, **kw):
-        real_init(self, *a, **kw)
-        if make_decode is not None:
-            self._decode = make_decode(self._decode, self.max_slots)
-
-    monkeypatch.setattr(eng_mod.Engine, "__init__", init)
-    if alter_token:
-        real_sample = eng_mod.sample_logits_per_row
-
-        def sample(*a, **kw):
-            tok = real_sample(*a, **kw)
-            return jnp.where(jnp.arange(tok.shape[0]) == 0,
-                             (tok + 1) % 512, tok)
-        monkeypatch.setattr(eng_mod, "sample_logits_per_row", sample)
-    rec = bench_tiny.run("tiny_sessions_closed", seed=77, seconds=3.0)
-    from bench.run import is_correct
-    return rec, is_correct(rec)
-
-
 def test_state_left_unchanged_is_not_correct(monkeypatch):
     """The decode step returns its KV pool unchanged."""
-    def make(decode, slots):
-        def stale(params, cache, tokens):
-            kept = jax.tree_util.tree_map(jnp.copy, cache)
-            logits, new = decode(params, cache, tokens)
-            kept["lens"] = new["lens"]
-            return logits, kept
-        return stale
-    rec, ok = _broken_run(monkeypatch, make_decode=make)
+    rec, ok = bench_tiny.broken_run(monkeypatch, "stale_state")
     assert not ok and rec["check"]["logit_gap"] > rec["check"]["limit"]
 
 
 def test_half_the_batch_left_out_is_not_correct(monkeypatch):
     """The decode step computes only the first half of its rows; the
     others take the first row's logits."""
-    def make(decode, slots):
-        def half(params, cache, tokens):
-            logits, new = decode(params, cache, tokens)
-            keep = jnp.arange(slots)[:, None] < slots // 2
-            return jnp.where(keep, logits, logits[:1]), new
-        return half
-    rec, ok = _broken_run(monkeypatch, make_decode=make)
+    rec, ok = bench_tiny.broken_run(monkeypatch, "half_batch")
     assert not ok
 
 
 def test_altered_token_is_not_correct(monkeypatch):
     """Sampling alters the token of the first row where it is made."""
-    rec, ok = _broken_run(monkeypatch, alter_token=True)
+    rec, ok = bench_tiny.broken_run(monkeypatch, "altered_token")
     assert not ok and rec["check"]["logit_gap"] > rec["check"]["limit"]
 
 
@@ -135,3 +99,4 @@ def test_closed_sessions_run_is_correct():
     assert rec["counters"]["prefix_hits"] > 0
     assert rec["admitted_cached_tokens"] > 0
     assert rec["check"]["requests"] >= 3 and is_correct(rec)
+

@@ -55,7 +55,9 @@ def test_mix_says_where_each_length_comes_from(name):
     """Each length is cited under ``sources`` or owned under ``assumed``."""
     mix = spec.resolve(name).traffic
     said = {k.split(".")[0] for k in [*mix["sources"], *mix["assumed"]]}
-    assert {"user", "answer", "system_prompts", "max_turns"} <= said
+    lengths = {k for k, v in mix.items()
+               if isinstance(v, dict) and ("dist" in v or "length" in v)}
+    assert {"user", "answer", "max_turns"} | lengths <= said
 
 
 def test_whys_and_sources_fit_the_contract():

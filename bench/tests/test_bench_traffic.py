@@ -1,6 +1,7 @@
 """The traffic generator: deterministic per seed, inside its bounds, the
 same sizes for every seed, and the sessions' bookkeeping."""
 
+import hashlib
 import json
 from collections import deque
 
@@ -131,3 +132,60 @@ def test_setup_turns_become_the_sessions_history():
         assert any(len(job.prompt) > len(sys_prompt)
                    and np.array_equal(job.prompt[:len(sys_prompt)], sys_prompt)
                    for sys_prompt in mix.system)
+
+
+def test_sessions_closed_draws_the_jobs_it_drew_before():
+    """The sessions mix's first 80 jobs for one seed, pinned when the
+    generator learned one-turn sessions (``data/tiny_pins.json``)."""
+    pin = json.loads((DATA / "tiny_pins.json").read_text())["sessions_closed"]
+    sig = [(len(j.prompt), j.max_new, j.tag, j.due,
+            hashlib.sha256(j.prompt.tobytes()).hexdigest()[:16])
+           for j in _drive("sessions_closed", pin["seed"], n=pin["jobs"])]
+    assert hashlib.sha256(json.dumps(sig).encode()).hexdigest() \
+        == pin["sha256"]
+
+
+def test_decode_closed_draws_the_same_lengths_for_every_seed():
+    """Every seed sends the same lengths in the same order, the first
+    wave and the requests after it; only the tokens differ."""
+    runs = [_drive("decode_closed", seed, n=16 + 64)
+            for seed in (2 ** 33 + 5, 3 * 2 ** 31 + 1)]
+    size = lambda j: (len(j.prompt), j.max_new, j.tag)
+    assert [size(j) for j in runs[0]] == [size(j) for j in runs[1]]
+    assert not any(np.array_equal(a.prompt, b.prompt) for a, b in zip(*runs))
+    assert len({j.max_new for j in runs[0][16:]}) > 20
+    assert all(j.due is None for j in runs[0])
+
+
+def test_decode_closed_opens_part_way_through_answers():
+    """Each first request carries the part of its answer already served
+    in its prompt and asks for the rest: prompt and remainder add up to a
+    user message (of the 16 quantiles) and an answer (of the 64) of the
+    mix's distributions.  Long answers,
+    which hold their slots longer, are met more often than they are
+    drawn."""
+    sp = _spec("decode_closed")
+    quant = lambda key, n=64: traffic.stratified(
+        sp[key], np.random.default_rng(0), n=n).tolist()
+    users, answers = set(quant("user", sp["sessions"])), quant("answer")
+    first = traffic.make_mix(sp, 2 ** 33 + 11, vocab=1000).first_jobs()
+    met = []
+    for job in first:
+        whole = len(job.prompt) + job.max_new
+        found = [whole - u for u in users
+                 if whole - u in answers and len(job.prompt) >= u]
+        assert found and job.max_new >= 1
+        met.append(min(found))
+    assert sorted(j.tag for j in first) == list(range(sp["sessions"]))
+    assert np.mean(met) > 1.5 * np.mean(answers)
+    assert max(len(j.prompt) for j in first) > max(users)
+
+
+def test_decode_closed_shares_no_page_of_prompt():
+    """Random prompts, no system prompt and one turn a session: no two
+    requests begin with the same page of tokens."""
+    jobs = _drive("decode_closed", 2 ** 33 + 9, n=200)
+    firsts = {j.prompt[:64].tobytes() for j in jobs}
+    assert len(firsts) == len(jobs)
+    assert traffic.make_mix(_spec("decode_closed"), 1, 1000).setup_jobs() \
+        == []

@@ -10,7 +10,7 @@ from bench import model as bm
 from bench import spec, weights
 from bench_tiny import reduced_conf
 from repro.core.policy import QuantPolicy
-from repro.core.quantization import dequantize
+from repro.core.quantization import QuantizedTensor, dequantize
 from repro.models import build_model
 
 CONFIGS = sorted(p.name for p in (spec.ROOT / "bench" / "configs").glob("*.json"))
@@ -49,8 +49,19 @@ def test_a_layout_the_program_does_not_serve_is_refused():
         bm.check_layout(model, dict(conf, intermediate_size=512))
 
 
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return np.asarray(dequantize(tree) if isinstance(tree, QuantizedTensor)
+                      else tree)
+
+
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_draws_the_served_weights(name):
+    """Every planned leaf of the served tree, dequantized, is what the
+    reference draws for it (the last layer of a stacked leaf); each of the
+    program's fused decode copies holds the rows of the drawn leaves its
+    architecture names, in that order."""
     conf = _conf(name)
     model = build_model(bm.model_config(conf))
     seed = 2 ** 40 + 3
@@ -58,15 +69,22 @@ def test_reference_draws_the_served_weights(name):
     plan = weights.plan(conf)
     key = weights.seed_key(seed)
     layer = model.cfg.n_layers - 1
-    drawn = weights.layer_weights(plan, key, layer)
-    wq = np.asarray(dequantize(params["blocks"]["attn"]["wq"]))[layer]
-    assert np.array_equal(np.asarray(drawn["blocks/attn/wq"]), wq)
-    gamma = np.asarray(params["blocks"]["norm2"]["gamma"])[layer]
-    assert np.array_equal(np.asarray(drawn["blocks/norm2/gamma"]), gamma)
-    top = weights.layer_weights(plan, key)
-    assert np.array_equal(np.asarray(top["embed"]),
-                          np.asarray(dequantize(params["embed"])))
-    # the fused decode copy is the concatenation of the drawn codes
-    wqkv = np.asarray(dequantize(params["blocks"]["attn"]["wqkv"]))[layer]
-    d = model.cfg.d_model
-    assert np.array_equal(wqkv[:wq.size // d], wq.reshape(-1, d))
+    drawn = {**weights.layer_weights(plan, key),
+             **weights.layer_weights(plan, key, layer)}
+    stacked = {e[0] for e in plan if e[3]}
+    for path in drawn:
+        served = _leaf(params, path)
+        served = served[layer] if path in stacked else served
+        assert np.array_equal(np.asarray(drawn[path]), served), path
+    flat = jax.tree_util.tree_flatten_with_path(
+        params, is_leaf=lambda x: isinstance(x, QuantizedTensor))[0]
+    fused = spec.arch(conf).fused_copies(conf)
+    assert sorted(bm._path_str(p) for p, _ in flat
+                  if bm._path_str(p) not in drawn) == sorted(fused)
+    for path, parts in fused.items():
+        served = _leaf(params, path)
+        served = served[layer] if served.ndim > 2 else served
+        width = served.shape[-1]
+        rows = np.concatenate([np.asarray(drawn[p]).reshape(-1, width)
+                               for p in parts])
+        assert np.array_equal(served.reshape(-1, width), rows), path

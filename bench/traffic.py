@@ -5,26 +5,32 @@ parameters of its lengths, arrivals and sharing.  One kind exists:
 
 ``sessions``  ``sessions`` multi-turn chats.  Each session opens with
               one of ``system_prompts`` (``count`` prompts, lengths from
-              ``length``, Zipf popularity ``zipf_s``); each turn appends a
-              ``user`` message to the history and asks for an ``answer``;
-              a session ends after ``max_turns`` turns or ``max_tokens``
-              tokens and a fresh one takes its place.  With
-              ``rate_per_s``, turns arrive at that rate with exponential
-              gaps and each goes to the session idle longest; without it,
-              every session sends its next turn as soon as its answer
-              completes (closed loop, no think time), starting with a turn
-              of each session in set-up.
+              ``length``, Zipf popularity ``zipf_s``), or with nothing
+              where the mix has none; each turn appends a ``user`` message
+              to the history and asks for an ``answer``; a session ends
+              after ``max_turns`` turns or ``max_tokens`` tokens and a
+              fresh one takes its place.  With ``rate_per_s``, turns
+              arrive at that rate with exponential gaps and each goes to
+              the session idle longest; without it, every session sends
+              its next turn as soon as its answer completes (closed loop,
+              no think time), starting with a turn of each session in
+              set-up.
               Set-up first serves every session's first turn, with an
               answer drawn from ``answer`` in place of a generated one, so
-              the window opens on sessions with history.
+              the window opens on sessions with history.  Sessions of one
+              turn have none to build: in a closed loop, set-up places
+              each part-way through an answer instead (:meth:`_mid_answer`),
+              so the window opens as in a loop that has run a while.
 
 Every length distribution (``lognormal`` with ``median`` and ``sigma``,
 ``uniform``, ``exponential`` with ``mean``, ``fixed`` with ``value``;
 ``min``/``max`` clip) is drawn *stratified*: each block of 64 draws is
 the distribution's 64 quantiles at (i + 0.5) / 64, in an order shuffled
 by the seed.  Every seed therefore
-sends the same sizes in each block, in another order.  Token ids are
-uniform over the vocabulary, from the seed.
+sends the same sizes in each block, in another order; with
+``same_order``, in the same order, and every other choice of the mix
+(arrivals, system prompts, sessions) is the same for every seed too.
+Token ids are uniform over the vocabulary, from the seed.
 """
 
 from __future__ import annotations
@@ -105,27 +111,33 @@ class Sessions:
     def __init__(self, spec: Dict, seed: int, vocab: int):
         self.spec, self.seed, self.vocab = spec, seed, vocab
         self._n_tokens = 0
-        sp = spec["system_prompts"]
-        lens = stratified(sp["length"], _rng(seed, 6), n=sp["count"])
-        self.system = [self.tokens(n) for n in lens]
-        w = np.arange(1, sp["count"] + 1, dtype=np.float64) ** -sp["zipf_s"]
-        self.popularity = w / w.sum()
+        order = 0 if spec.get("same_order") else seed
+        sp = spec.get("system_prompts")
+        if sp is None:
+            self.system = [np.zeros(0, np.int32)]
+            self.popularity = np.ones(1)
+        else:
+            lens = stratified(sp["length"], _rng(order, 6), n=sp["count"])
+            self.system = [self.tokens(n) for n in lens]
+            rank = np.arange(1, sp["count"] + 1, dtype=np.float64)
+            w = rank ** -sp["zipf_s"]
+            self.popularity = w / w.sum()
         self._sys_order: Deque[int] = deque()
-        self._sys_rng = _rng(seed, 7)
+        self._sys_rng = _rng(order, 7)
         self.closed = "rate_per_s" not in spec
         if not self.closed:
             self.gaps = _Stream({"dist": "exponential",
                                  "mean": 1.0 / spec["rate_per_s"]},
-                                _rng(seed, 5), integer=False)
-        self.user = _Stream(spec["user"], _rng(seed, 2))
-        self.answer = _Stream(spec["answer"], _rng(seed, 3))
+                                _rng(order, 5), integer=False)
+        self.user = _Stream(spec["user"], _rng(order, 2))
+        self.answer = _Stream(spec["answer"], _rng(order, 3))
         self.history: List[np.ndarray] = []
         self.turns: List[int] = []
         for _ in range(spec["sessions"]):
             self.history.append(self._fresh())
             self.turns.append(0)
         self.idle: Deque[int] = deque(
-            _rng(seed, 8).permutation(spec["sessions"]).tolist())
+            _rng(order, 8).permutation(spec["sessions"]).tolist())
         self.t_next = None if self.closed else self.gaps.next()
         self.backlog: Deque[float] = deque()
 
@@ -161,6 +173,8 @@ class Sessions:
         return Job(np.concatenate(parts).astype(np.int32), max_new, s, due)
 
     def setup_jobs(self) -> List[Job]:
+        if self.spec["max_turns"] <= 1:
+            return []
         # a first turn whose answer is drawn, not generated: the prompt
         # carries it, and the set-up request decodes just enough to warm
         # the decode step
@@ -174,7 +188,35 @@ class Sessions:
     def first_jobs(self) -> List[Job]:
         if not self.closed:
             return []
+        if self.spec["max_turns"] <= 1:
+            return self._mid_answer()
         jobs = [self._turn(s, None) for s in self.idle]
+        self.idle.clear()
+        return jobs
+
+    def _mid_answer(self) -> List[Job]:
+        """The first turns of one-turn sessions, each part-way through an
+        answer, as a closed loop that has run a while holds them.  The
+        answers are ``answer``'s 64 quantiles laid end to end, each as
+        long as its tokens; session j stands at (j + 0.5) / sessions of
+        the whole, so a long answer, which holds its slot longer, is met
+        more often, at a point spread over its length.  The part already
+        served rides in the prompt after a ``user`` message (that
+        distribution's quantiles, in a fixed order), and the job asks for
+        the rest.  Every seed places the same points."""
+        n = len(self.idle)
+        answers = np.sort(stratified(self.spec["answer"], _rng(0)))
+        ends = np.cumsum(answers)
+        at = (np.arange(n) + 0.5) / n * ends[-1]
+        which = np.searchsorted(ends, at, side="right")
+        served = (at - ends[which] + answers[which]).astype(np.int64)
+        users = stratified(self.spec["user"], _rng(0, 1), n=n)
+        jobs = []
+        for j, s in enumerate(self.idle):
+            prompt = np.concatenate([self.history[s], self.tokens(users[j]),
+                                     self.tokens(served[j])])
+            jobs.append(Job(prompt.astype(np.int32),
+                            int(answers[which[j]] - served[j]), s))
         self.idle.clear()
         return jobs
 
